@@ -24,7 +24,7 @@ from typing import Dict, Optional
 
 from ..obs import tracebuf as _tracebuf
 from ..obs.reconcile import ReconcileRecorder, register_controller
-from ..store import APIStore
+from ..store import APIStore, CoalescedEvent, ResourceVersionTooOldError
 from ..utils import Clock
 
 
@@ -33,6 +33,14 @@ class Controller:
     and `sync(key)`. Drive with pump()+process() (tests) or start() (daemon)."""
 
     watch_kinds: tuple = ()
+    # first wait between relists when the resume is too old; doubles to the
+    # cap while relists keep failing (client-go's reflector backoff)
+    RELIST_BACKOFF_S = 0.1
+    RELIST_BACKOFF_MAX_S = 5.0
+    # keys synced between two pumps of the watch: events arriving during a
+    # drain wait in the bounded buffer, so a drain must end before it fills
+    # (10k nodes renew 1,000 leases a second; a 10,000-key drain did not)
+    DRAIN_KEYS = 1000
 
     def __init__(self, store: APIStore, clock: Optional[Clock] = None,
                  telemetry: bool = True):
@@ -47,6 +55,9 @@ class Controller:
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
         self.sync_errors = 0
+        # relists after the first LIST (an evicted watch, a too-old resume):
+        # a count that keeps climbing is a controller relisting in a loop
+        self.relists = 0
         # per-loop reconcile recorder (ISSUE 9). telemetry=False keeps the
         # recorder fully inert AND unregistered — the parity oracle for the
         # recorder-on/off byte-identical tests.
@@ -61,17 +72,34 @@ class Controller:
     # -- event intake ----------------------------------------------------------
 
     def sync_all(self) -> None:
-        """Initial LIST: mark every existing object of the primary kind dirty."""
-        lists, rv = self.store.list_many(self.watch_kinds)
-        now = self.clock.now()  # ONE shared first-marked stamp for the seed
-        for kind in self.watch_kinds:
-            for obj in lists[kind]:
-                key = self.key_of_object(kind, obj)
-                if key:
-                    self._mark(key, now)
-        # kind-filtered subscription: high-volume kinds this controller
-        # ignores (e.g. events) never consume its watch buffer
-        self._watch = self.store.watch(kind=set(self.watch_kinds), since_rv=rv)
+        """Initial LIST: mark every existing object of the primary kind dirty,
+        then watch from the list's RV. A burst of more events than the watch
+        buffer holds between the two makes the resume too old: list again
+        after a backoff (the Reflector contract) instead of letting the error
+        end the controller's thread."""
+        backoff = self.RELIST_BACKOFF_S
+        while True:
+            lists, rv = self.store.list_many(self.watch_kinds)
+            now = self.clock.now()  # ONE shared first-marked stamp for the seed
+            for kind in self.watch_kinds:
+                for obj in lists[kind]:
+                    key = self.key_of_object(kind, obj)
+                    if key:
+                        self._mark(key, now)
+            # kind-filtered subscription: high-volume kinds this controller
+            # ignores (e.g. events) never consume its watch buffer. Coalesced:
+            # a batched write (a create_many or bind_many chunk) is ONE
+            # buffered item, so a 100k-pod burst cannot overflow the buffer
+            # and force a relist of 100k pods in every controller
+            try:
+                self._watch = self.store.watch(kind=set(self.watch_kinds),
+                                               since_rv=rv, coalesce=True)
+                return
+            except ResourceVersionTooOldError:
+                self.relists += 1
+                if self._stop.wait(backoff):
+                    return
+                backoff = min(2 * backoff, self.RELIST_BACKOFF_MAX_S)
 
     def pump(self, max_events: int = 10_000) -> int:
         if self._watch is None:
@@ -79,6 +107,7 @@ class Controller:
         if self._watch.terminated:
             # evicted as a slow watcher: relist + rewatch (Reflector contract)
             self._watch.stop()
+            self.relists += 1
             self.sync_all()
             return 0
         t0 = time.perf_counter()
@@ -87,8 +116,11 @@ class Controller:
         # bounded drain: events beyond the cap stay buffered for the next
         # pump (breaking out of a full drain() would DISCARD them — the bug
         # that truncated the scheduler's 100k backlog)
-        for ev in self._watch.drain(max_events):
-            if ev.kind in self.watch_kinds:
+        for item in self._watch.drain(max_events):
+            if item.kind not in self.watch_kinds:
+                continue
+            for ev in (item.events if type(item) is CoalescedEvent
+                       else (item,)):
                 key = self.key_of_object(ev.kind, ev.obj)
                 if key:
                     self._mark(key, now)
@@ -140,7 +172,7 @@ class Controller:
 
     def reconcile_once(self) -> int:
         self.pump()
-        return self.process()
+        return self.process(self.DRAIN_KEYS)
 
     def run_until_stable(self, max_rounds: int = 50) -> None:
         for _ in range(max_rounds):
@@ -169,6 +201,7 @@ class Controller:
         """The /debug/controlstats payload for this controller."""
         out = self.recorder.snapshot()
         out["depth"] = self.workqueue_depth()
+        out["relists"] = self.relists
         out["oldest_dirty_age_s"] = round(self.oldest_dirty_age_s(), 3)
         out["watch_kinds"] = list(self.watch_kinds)
         return out

@@ -57,7 +57,9 @@ class GarbageCollector(Controller):
         """Full-store orphan scan (the GC's graph resync). Returns #deleted."""
         deleted = 0
         for kind in list(self.store.kinds()):
-            objs, _ = self.store.list(kind)
+            # only dependents can be orphans: the rest is never copied
+            objs, _ = self.store.list(
+                kind, lambda o: bool(o.metadata.owner_references))
             for obj in objs:
                 if self._is_orphan(obj):
                     if self._delete(kind, self.store.object_key(obj)):

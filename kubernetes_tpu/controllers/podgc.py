@@ -40,9 +40,14 @@ class PodGCController(Controller):
 
     def sweep(self) -> int:
         deleted = 0
-        pods, _ = self.store.list("pods")
-        node_names = {n.metadata.name
-                      for n in self.store.list("nodes")[0]}
+        node_names = set(self.store.keys("nodes"))
+        # the predicate picks the candidates of all three sweeps, so only
+        # they are copied — not the whole running population every 20 s
+        pods, _ = self.store.list("pods", lambda p: (
+            (p.spec.node_name and p.spec.node_name not in node_names)
+            or (p.metadata.deletion_timestamp is not None
+                and not p.spec.node_name)
+            or p.is_terminal()))
 
         # orphaned: bound to a node that is gone (gcOrphaned) — the kubelet
         # that would run them no longer exists, so nothing else reaps them

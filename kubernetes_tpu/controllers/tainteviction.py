@@ -9,7 +9,7 @@ tolerationSeconds keep the pod indefinitely.
 
 from __future__ import annotations
 
-from typing import Dict, Optional
+from typing import Dict, Optional, Set
 
 from ..api import Pod
 from ..api.types import TAINT_NO_EXECUTE
@@ -22,23 +22,36 @@ class TaintEvictionController(Controller):
 
     def __init__(self, store, clock=None):
         super().__init__(store, clock)
-        # pod key -> (eviction deadline, taint-set signature that produced it).
+        # pod key -> (eviction deadline, taint-set signature that produced it,
+        # the pod's node).
         # The signature lets a taint-set change cancel+reschedule the timed
         # eviction (TimedWorkerQueue semantics) in either direction — a new
         # tighter taint shortens the deadline, removing the tight taint
         # restores the longer one — without the deadline sliding forward on
         # every no-change resync.
         self._deadlines: Dict[str, tuple] = {}
+        # nodes whose last event carried a NoExecute taint (kept by
+        # key_of_object from the node stream, which orders it with the pods')
+        self._noexec_nodes: Set[str] = set()
 
     def key_of_object(self, kind: str, obj) -> Optional[str]:
         if kind == "nodes":
-            return obj.metadata.name
-        return f"pod|{obj.key}" if obj.spec.node_name else None
+            name = obj.metadata.name
+            if any(t.effect == TAINT_NO_EXECUTE for t in obj.spec.taints):
+                self._noexec_nodes.add(name)
+            else:
+                self._noexec_nodes.discard(name)
+            return name
+        # a pod on a node with no NoExecute taint has nothing to count down
+        # (a pod read and a node read per bind, 100k of them in a burst);
+        # a node tainted later re-examines its pods through its own key
+        node = obj.spec.node_name
+        return f"pod|{obj.key}" if node and node in self._noexec_nodes else None
 
     def tick(self) -> None:
         """Fire due timed evictions (the reference's TimedWorkerQueue)."""
         now = self.clock.now()
-        for pod_key, (deadline, _sig) in list(self._deadlines.items()):
+        for pod_key, (deadline, _sig, _node) in list(self._deadlines.items()):
             if deadline <= now:
                 self._deadlines.pop(pod_key, None)
                 self._evict(pod_key)
@@ -66,12 +79,17 @@ class TaintEvictionController(Controller):
         except NotFoundError:
             return
         taints = [t for t in node.spec.taints if t.effect == TAINT_NO_EXECUTE]
+        if not taints:
+            # cancel this node's countdowns by walking the countdowns, not the
+            # cluster's pods: a relist marks every node, and a pod LIST per
+            # node made that O(nodes x pods) — minutes of the interpreter at
+            # 10k nodes / 100k pods, starving the scheduler's thread
+            for pod_key in [k for k, d in self._deadlines.items()
+                            if d[2] == key]:
+                del self._deadlines[pod_key]
+            return
         pods, _ = self.store.list("pods", lambda p: p.spec.node_name == key
                                   and not p.is_terminal())
-        if not taints:
-            for p in pods:
-                self._deadlines.pop(p.key, None)
-            return
         for p in pods:
             self._check_pod(p, node=node)
 
@@ -107,7 +125,8 @@ class TaintEvictionController(Controller):
             if existing is None or existing[1] != sig:
                 # new countdown, or the taint set changed: cancel + reschedule
                 # from now with the recomputed minimum (may tighten or loosen)
-                self._deadlines[pod.key] = (self.clock.now() + min_seconds, sig)
+                self._deadlines[pod.key] = (self.clock.now() + min_seconds,
+                                            sig, pod.spec.node_name)
 
     def _evict(self, pod_key: str) -> None:
         try:

@@ -46,7 +46,7 @@ import numpy as np
 
 from ..ops.solver import SolverInputs, pod_row_feasibility_score
 
-NEG_INF = jnp.float32(-1e30)
+NEG_INF = np.float32(-1e30)
 
 
 class GroupProblem(NamedTuple):
@@ -486,9 +486,7 @@ def transport_solve(
         if node_names is None:
             # duals must map to TRUE nodes, never mesh padding
             node_names = [str(i) for i in range(true_n)]
-        from ..parallel import mesh_context
-
-        ctx = mesh_context(mesh)
+        ctx = jax.sharding.set_mesh(mesh)
     with ctx:
         if method == "sinkhorn":
             frac, new_state = sinkhorn_solve(problem, state, node_names)

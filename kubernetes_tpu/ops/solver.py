@@ -29,7 +29,7 @@ import numpy as np
 
 from ..scheduler.framework import MAX_NODE_SCORE
 
-INT_MIN = jnp.int32(-(2**31) + 1)
+INT_MIN = np.int32(-(2**31) + 1)
 
 
 class SolverInputs(NamedTuple):

@@ -1,18 +1,1 @@
 """Device mesh construction and sharded solvers (ICI-scale node/pod axes)."""
-
-from __future__ import annotations
-
-
-def mesh_context(mesh):
-    """`jax.sharding.set_mesh(mesh)`-compatible context manager across jax
-    versions (the ROADMAP env gap: this toolchain's jax build predates the
-    public set_mesh). Every caller here device_puts its arrays with explicit
-    NamedShardings, so on older builds the legacy `with mesh:` resource-env
-    context is sufficient — GSPMD partitioning and replica groups come out
-    identical (pinned by the sharded-parity tests)."""
-    import jax
-
-    set_mesh = getattr(jax.sharding, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh  # jax.sharding.Mesh is itself a context manager

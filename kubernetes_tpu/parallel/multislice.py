@@ -121,9 +121,7 @@ def audit_collectives(fn, mesh: Mesh, *args, dcn_ok: Sequence[str] = (),
     This is the profile-free version of "look at the xplane and check which
     collectives ride which fabric": replica groups are decided at compile
     time, so locality is checkable without hardware."""
-    from . import mesh_context
-
-    with mesh_context(mesh):
+    with jax.sharding.set_mesh(mesh):
         compiled = jax.jit(fn).lower(*args, **kwargs).compile()
     text = compiled.as_text()
     # device id -> slice row

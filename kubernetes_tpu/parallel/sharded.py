@@ -18,7 +18,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from . import mesh_context
 from ..ops.solver import SolverInputs, greedy_scan_solve
 from ..scheduler.framework import MAX_NODE_SCORE
 
@@ -101,7 +100,7 @@ def sharded_greedy_solve(inp: SolverInputs, d_max: int, mesh: Mesh):
     per-step filter/score over the mesh and inserts the argmax/segment-sum
     collectives. Assignment indices refer to the padded node axis; callers must
     treat idx >= true_n as unschedulable (cannot happen: padding is infeasible)."""
-    with mesh_context(mesh):
+    with jax.sharding.set_mesh(mesh):
         return greedy_scan_solve(inp, d_max)
 
 
@@ -124,7 +123,7 @@ def sharded_feasibility_cost(inp: SolverInputs, d_max: int, mesh: Mesh):
     fn = jax.jit(feasibility_cost_matrices, static_argnames=("d_max",),
                  out_shardings=(NamedSharding(mesh, P("dp", "nodes")),
                                 NamedSharding(mesh, P("dp", "nodes"))))
-    with mesh_context(mesh):
+    with jax.sharding.set_mesh(mesh):
         return fn(inp, d_max)
 
 

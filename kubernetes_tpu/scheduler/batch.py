@@ -167,6 +167,9 @@ class BatchScheduler(Scheduler):
         # MODE label alone would credit a constrained batch's scan run to
         # the fast path (scheduler/breaker.py path_matches_mode)
         self._solve_path = "exact"
+        # successful device solves per executed path (fast/repair/exact/...):
+        # which kernels a run really used, for sched_stats and the chip smoke
+        self.solve_paths: Dict[str, int] = {}
         # constraint propose-and-repair observability (ISSUE 8): the last
         # batch's RepairStats (feeds the flight record) + running totals for
         # sched_stats/ktl — a pathological repair loop (rounds pinned at the
@@ -460,6 +463,8 @@ class BatchScheduler(Scheduler):
                 assignment = None
             else:
                 self.breaker.record_success(self._solve_path, self.solver)
+                self.solve_paths[self._solve_path] = (
+                    self.solve_paths.get(self._solve_path, 0) + 1)
         if device_idx.size and assignment is not None:
             # All-or-nothing gang veto (scheduler/gang.py), BEFORE any assume
             # or bind: a gang whose in-batch placements (plus members already
@@ -1487,6 +1492,7 @@ class BatchScheduler(Scheduler):
                        else dict(self.repair_totals)
                        if self.repair_totals["batches"] else None),
             "breaker": self.breaker.describe(),
+            "solve_paths": dict(self.solve_paths),
             # partitioned mode (ISSUE 12): this pipeline's shard identity +
             # the absorbed cross-partition races; None standalone
             "partition": ({

@@ -777,14 +777,38 @@ def build_pod_batch(pods: Sequence[Pod], snapshot: Snapshot,
     sc = len(selcls_matchers)
     selcls_key_tuple = tuple(selcls_idx.keys())
 
-    def _count_node_column(ni) -> np.ndarray:
-        col = np.zeros(sc, dtype=np.int32)
-        for pinfo in ni.pods:
-            p = pinfo.pod
-            for si, matcher in enumerate(selcls_matchers):
-                if matcher(p):
-                    col[si] += 1
-        return col
+    # Every selector-class matcher reads only a pod's namespace, labels and
+    # whether it is terminating (PTS counting excludes terminating pods), so
+    # pods that agree on those three match the same rows: each distinct
+    # signature runs the matchers once, however many pods carry it, so the
+    # walk costs O(pods + signatures x classes), not O(pods x classes).
+    sig_rows: Dict[tuple, np.ndarray] = {}
+
+    def _matched_rows(p) -> np.ndarray:
+        md = p.metadata
+        sig = (md.namespace,
+               tuple(sorted(md.labels.items())) if md.labels else (),
+               md.deletion_timestamp is None)
+        rows = sig_rows.get(sig)
+        if rows is None:
+            rows = sig_rows[sig] = np.asarray(
+                [si for si, matcher in enumerate(selcls_matchers)
+                 if matcher(p)], dtype=np.intp)
+        return rows
+
+    def _count_nodes(counts: np.ndarray, nodes) -> None:
+        """Write the matching-pod counts of `nodes` into `counts` [sc, n]."""
+        row_parts, node_parts = [], []
+        for nidx in nodes:
+            counts[:, nidx] = 0
+            for pinfo in snapshot.node_info_list[nidx].pods:
+                rows = _matched_rows(pinfo.pod)
+                if rows.size:
+                    row_parts.append(rows)
+                    node_parts.append(np.full(rows.size, nidx, np.intp))
+        if row_parts:
+            np.add.at(counts, (np.concatenate(row_parts),
+                               np.concatenate(node_parts)), 1)
 
     # IPA namespaceSelector matchers resolve against the live ns_labels
     # table, which the selector-class keys do NOT capture — a namespace
@@ -802,13 +826,10 @@ def build_pod_batch(pods: Sequence[Pod], snapshot: Snapshot,
             and reuse.selcls_count.shape == (sc, cluster.n)):
         # incremental: only changed nodes rescan their pods
         selcls_count = reuse.selcls_count
-        for nidx in changed_nodes:
-            selcls_count[:, nidx] = _count_node_column(
-                snapshot.node_info_list[nidx])
+        _count_nodes(selcls_count, changed_nodes)
     else:
         selcls_count = np.zeros((sc, cluster.n), dtype=np.int32)
-        for nidx, ni in enumerate(snapshot.node_info_list):
-            selcls_count[:, nidx] = _count_node_column(ni)
+        _count_nodes(selcls_count, range(len(snapshot.node_info_list)))
     if reuse is not None:
         reuse.selcls_keys = selcls_key_tuple
         reuse.selcls_count = selcls_count
@@ -818,9 +839,7 @@ def build_pod_batch(pods: Sequence[Pod], snapshot: Snapshot,
     # cross-match: placing a pod of class c bumps counts of selector-class sc?
     class_matches = np.zeros((len(rep_pods), max(sc, 1)), dtype=np.int32)
     for ci, pod in enumerate(rep_pods):
-        for si, matcher in enumerate(selcls_matchers):
-            if matcher(pod):
-                class_matches[ci, si] = 1
+        class_matches[ci, _matched_rows(pod)] = 1
 
     def rows_to_arrays(rows, with_min_domains):
         if not rows:

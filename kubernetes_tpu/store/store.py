@@ -1273,9 +1273,14 @@ class APIStore:
             if kind == "pods":
                 self._materialize_pod_rows()
             items = list(self._objects.get(kind, {}).values())
-            if predicate is not None:
-                items = [o for o in items if predicate(o)]
-            return [self._copy(o) for o in items], self._rv
+            rv = self._rv
+        # filter and copy the snapshot outside the lock: stored objects are
+        # never mutated in place (writes replace them), so the refs taken
+        # above stay the RV's state, and a LIST of 100k pods does not hold
+        # every writer and every API request for the seconds its copies take
+        if predicate is not None:
+            items = [o for o in items if predicate(o)]
+        return [self._copy(o) for o in items], rv
 
     def list_many(self, kinds: Iterable[str]) -> Tuple[Dict[str, List[Any]], int]:
         """Consistent multi-kind snapshot under one RV — the safe way to seed an
@@ -1297,8 +1302,17 @@ class APIStore:
         with lock:
             if has_pods:
                 self._materialize_pod_rows()
-            out = {k: [self._copy(o) for o in self._objects.get(k, {}).values()] for k in kinds}
-            return out, self._rv
+            refs = {k: list(self._objects.get(k, {}).values()) for k in kinds}
+            rv = self._rv
+        # copies outside the lock, as in list()
+        return {k: [self._copy(o) for o in objs]
+                for k, objs in refs.items()}, rv
+
+    def keys(self, kind: str) -> List[str]:
+        """The keys of every stored object of `kind` — a LIST that copies
+        nothing, for callers that only need to know what exists."""
+        with self._kind_lock(kind):
+            return list(self._objects.get(kind, {}))
 
     def resource_version(self) -> int:
         with self._lock:

@@ -281,3 +281,94 @@ def test_deployment_scale_down():
         dc.reconcile_once()
         rsc.reconcile_once()
     assert len(store.list("pods")[0]) == 2
+
+
+def test_sync_all_relists_when_a_burst_outruns_the_watch_buffer():
+    """A burst of more events than the watch buffer holds, landing between a
+    controller's LIST and its WATCH, makes the resume too old. sync_all must
+    list again and watch, not raise: raised in a controller's thread (or the
+    leader's start callback) it ended the controller on the chip smoke's
+    100k-pod burst."""
+    from kubernetes_tpu.store import Watch
+    from kubernetes_tpu.testing import MakePod
+
+    store = APIStore()
+    rsc = ReplicaSetController(store)
+    orig = store.list_many
+    calls = []
+
+    def list_then_burst(kinds):
+        out = orig(kinds)
+        calls.append(out[1])
+        if len(calls) == 1:
+            store.create_many("pods", (MakePod(f"b-{i}").obj() for i in
+                                       range(Watch.DEFAULT_MAXSIZE)),
+                              consume=True)
+        return out
+
+    store.list_many = list_then_burst
+    store.create("replicasets", make_rs(replicas=1))
+    rsc.sync_all()
+    assert len(calls) == 2 and calls[1] > calls[0]
+    assert rsc._watch is not None and not rsc._watch.terminated
+    assert rsc.relists == 1 and rsc.reconcile_stats()["relists"] == 1
+
+
+def test_reconcile_pumps_between_bounded_drains():
+    """reconcile_once syncs at most DRAIN_KEYS keys before it pumps the
+    watch again: a drain of every dirty key at 10k nodes outlasted the watch
+    buffer under lease renewals and evicted the watch."""
+    from kubernetes_tpu.controllers.base import Controller
+    from kubernetes_tpu.testing import MakePod
+
+    synced = []
+
+    class PodKeys(Controller):
+        watch_kinds = ("pods",)
+
+        def key_of_object(self, kind, obj):
+            return obj.key
+
+        def sync(self, key):
+            synced.append(key)
+
+    store = APIStore()
+    ctl = PodKeys(store, telemetry=False)
+    ctl.sync_all()
+    n = 2 * Controller.DRAIN_KEYS + 5
+    store.create_many("pods", (MakePod(f"b-{i}").obj() for i in range(n)),
+                      consume=True)
+    assert ctl.reconcile_once() == Controller.DRAIN_KEYS
+    ctl.run_until_stable()
+    assert len(synced) == n and not ctl._dirty
+
+
+def test_burst_larger_than_the_watch_buffer_forces_no_relist():
+    """Controllers watch coalesced: a create_many chunk is one buffered item,
+    so a burst of several buffers' worth of pods between two pumps neither
+    evicts the watch nor relists, and every pod still reaches the key
+    function."""
+    from kubernetes_tpu.controllers.base import Controller
+    from kubernetes_tpu.store import Watch
+    from kubernetes_tpu.testing import MakePod
+
+    class PodKeys(Controller):
+        watch_kinds = ("pods",)
+
+        def key_of_object(self, kind, obj):
+            return obj.key
+
+        def sync(self, key):
+            pass
+
+    store = APIStore()
+    ctl = PodKeys(store, telemetry=False)
+    ctl.sync_all()
+    n = 2 * Watch.DEFAULT_MAXSIZE + 7
+    for lo in range(0, n, 5000):
+        store.create_many("pods", (MakePod(f"b-{i}").obj()
+                                   for i in range(lo, min(lo + 5000, n))),
+                          consume=True)
+    assert ctl.pump() == n
+    assert not ctl._watch.terminated and ctl.relists == 0
+    assert len(ctl._dirty) == n

@@ -9,12 +9,7 @@ read-modify-write, which round 4's review actually caught by hand).
 
 import string
 
-import pytest
-
-# env gap (ROADMAP): the fuzzing harness isn't baked into every toolchain
-# image — collection must skip cleanly, not error, when it's absent
-pytest.importorskip("hypothesis")
-from hypothesis import given, settings, strategies as st  # noqa: E402
+from hypothesis import given, settings, strategies as st
 
 from kubernetes_tpu.api.serialize import from_dict, to_dict
 

@@ -116,14 +116,8 @@ class TestCollectiveLocality:
             # sum over the dp (DCN) axis: all-reduce groups span rows
             return jax.lax.psum(v.sum(axis=0), axis_name="dp")
 
-        # env gap (ROADMAP): shard_map graduated to jax.shard_map after this
-        # toolchain's build; fall back to its experimental home
-        shard_map = getattr(jax, "shard_map", None)
-        if shard_map is None:
-            from jax.experimental.shard_map import shard_map
-
         def fn(v):
-            return shard_map(crossing, mesh=mesh, in_specs=P("dp", "nodes"),
+            return jax.shard_map(crossing, mesh=mesh, in_specs=P("dp", "nodes"),
                              out_specs=P("nodes"))(v)
 
         with pytest.raises(AssertionError):

@@ -330,3 +330,38 @@ def test_lock_order_check_off_by_default(monkeypatch):
     monkeypatch.delenv("STORE_LOCK_ORDER_CHECK", raising=False)
     s = APIStore()
     assert type(s._lock).__name__ == "RLock"
+
+
+def test_list_filters_and_copies_outside_the_lock():
+    """A LIST takes its snapshot under the lock and filters and copies it
+    after: a writer must get through while a 100k-pod LIST is copying (the
+    held lock starved the API server's request thread on the chip smoke)."""
+    store = APIStore()
+    store.create_many("pods", [MakePod(f"p{i}").obj() for i in range(3)])
+    wrote = []
+
+    def writer():
+        store.create("pods", MakePod("during").obj())
+        wrote.append(True)
+
+    def predicate(p):
+        if not wrote:
+            t = threading.Thread(target=writer)
+            t.start()
+            t.join(timeout=5)
+        return True
+
+    pods, rv = store.list("pods", predicate)
+    assert wrote, "the writer was blocked while the LIST filtered"
+    # the LIST is still the snapshot at its RV: the concurrent create is not in it
+    assert {p.metadata.name for p in pods} == {"p0", "p1", "p2"}
+    assert store.get("pods", "default/during").metadata.resource_version > rv
+
+
+def test_keys_lists_without_copies():
+    store = APIStore()
+    store.create("nodes", MakeNode("n1").obj())
+    store.create("pods", MakePod("p").obj())
+    assert store.keys("nodes") == ["n1"]
+    assert store.keys("pods") == ["default/p"]
+    assert store.keys("services") == []

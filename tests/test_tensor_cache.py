@@ -189,3 +189,66 @@ class TestTensorCache:
         assert b2.req.dtype == np.int32
         # the fast path actually engaged (shares the pod-axis arrays)
         assert b2.class_of_pod is b1.class_of_pod
+
+
+def test_selector_counts_match_a_per_pod_walk():
+    """Selector-class counts are computed once per (namespace, labels,
+    terminating) signature; they must equal a plain walk of every bound pod
+    against each class's selector: a spread selector counts live pods of its
+    namespace only, an anti-affinity term counts its own group only."""
+    host = "kubernetes.io/hostname"
+    cache = Cache(clock=FakeClock())
+    for i in range(12):
+        cache.add_node(MakeNode(f"n{i}").labels({ZONE: f"z{i % 3}",
+                                                 host: f"n{i}"})
+                       .capacity({"cpu": "16", "pods": "110"}).obj())
+    bound = []
+    for i in range(60):
+        mk = MakePod(f"b{i}").req({"cpu": "100m"})
+        mk = mk.namespace("other" if i % 7 == 0 else "default")
+        mk = mk.labels({"app": "spread", "grp": f"g{i % 5}"} if i % 3
+                       else {"app": "web"})
+        p = mk.obj()
+        if i % 11 == 0:
+            p.metadata.deletion_timestamp = 1.0
+        p.spec.node_name = f"n{(i * 5) % 12}"
+        cache.add_pod(p)
+        bound.append(p)
+    batch_pods = [MakePod(f"q{i}").labels({"app": "spread", "grp": f"g{i % 5}"})
+                  .req({"cpu": "100m"})
+                  .topology_spread(1, ZONE, "DoNotSchedule", {"app": "spread"})
+                  .pod_anti_affinity(host, {"grp": f"g{i % 5}"}).obj()
+                  for i in range(10)]
+    snap = cache.update_snapshot()
+    cluster = build_cluster_tensors(snap)
+    batch = build_pod_batch(batch_pods, snap, cluster)
+    names = cluster.node_names
+
+    def walk(pred):
+        col = np.zeros(len(names), dtype=np.int32)
+        for p in bound:
+            if pred(p):
+                col[names.index(p.spec.node_name)] += 1
+        return col
+
+    spread = walk(lambda p: p.metadata.namespace == "default"
+                  and p.metadata.deletion_timestamp is None
+                  and p.metadata.labels.get("app") == "spread")
+    assert batch.ct_sel.size
+    for t in range(batch.ct_sel.size):
+        np.testing.assert_array_equal(
+            cluster.selcls_count[batch.ct_sel[t]], spread)
+    rn_sel, rn_key = batch.ipa.rn_sel, batch.ipa.rn_key
+    checked = set()
+    for i, pod in enumerate(batch_pods):
+        c = int(batch.class_of_pod[i])
+        grp = pod.metadata.labels["grp"]
+        for j in range(rn_key.shape[1]):
+            if rn_key[c, j] < 0:
+                continue
+            want = walk(lambda p: p.metadata.namespace == "default"
+                        and p.metadata.labels.get("grp") == grp)
+            np.testing.assert_array_equal(
+                cluster.selcls_count[rn_sel[c, j]], want)
+            checked.add(grp)
+    assert checked == {f"g{k}" for k in range(5)}
