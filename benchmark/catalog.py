@@ -1,0 +1,79 @@
+"""Everything a run needs, found by name from BENCHMARK.json: the cell's
+configuration file, its traffic mix (`benchmark/traffic/<traffic>.json`)
+and one reader per metric (`benchmark/metrics/<metric>.py`, whose
+`read(window)` returns the number, or None when it finds nothing to read).
+A cell, a configuration, a mix or a metric is added by adding files and
+entries; nothing here names one.
+"""
+
+from __future__ import annotations
+
+import importlib.util
+import json
+import os
+from dataclasses import dataclass
+from typing import Callable, Dict, List
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@dataclass
+class Cell:
+    name: str
+    chips: int
+    config_name: str
+    config: dict
+    traffic_name: str
+    traffic: dict
+    end_to_end: List[dict]
+    per_layer: List[dict]
+
+
+def load_spec(root: str = ROOT) -> dict:
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+def _reports(metric: dict, cell: str) -> bool:
+    return "workloads" not in metric or cell in metric["workloads"]
+
+
+def load_cell(name: str, root: str = ROOT) -> Cell:
+    spec = load_spec(root)
+    cells = {w["name"]: w for w in spec["workloads"]}
+    if name not in cells:
+        raise KeyError(f"no workload {name!r} in BENCHMARK.json "
+                       f"(have {sorted(cells)})")
+    w = cells[name]
+    configs = {c["name"]: c for c in spec["configs"]}
+    with open(os.path.join(root, configs[w["config"]]["file"])) as f:
+        config = json.load(f)
+    with open(os.path.join(root, "benchmark", "traffic",
+                           w["traffic"] + ".json")) as f:
+        traffic = json.load(f)
+    return Cell(name=name, chips=w["chips"], config_name=w["config"],
+                config=config, traffic_name=w["traffic"], traffic=traffic,
+                end_to_end=[m for m in spec["end_to_end"] if _reports(m, name)],
+                per_layer=[m for m in spec["per_layer"] if _reports(m, name)])
+
+
+def load_reader(metric: str, root: str = ROOT) -> Callable:
+    path = os.path.join(root, "benchmark", "metrics", metric + ".py")
+    spec = importlib.util.spec_from_file_location(
+        "benchmark_metric_" + metric.replace(".", "_").replace("-", "_"), path)
+    if spec is None or spec.loader is None:
+        raise FileNotFoundError(path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+def read_metrics(metrics: List[dict], window, root: str = ROOT) -> Dict:
+    """{name: {"value", "unit"}} for every metric whose reader found a
+    number; a reader that finds nothing leaves its metric out."""
+    out = {}
+    for m in metrics:
+        v = load_reader(m["name"], root)(window)
+        if v is not None:
+            out[m["name"]] = {"value": v, "unit": m["unit"]}
+    return out
