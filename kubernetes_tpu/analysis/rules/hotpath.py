@@ -46,6 +46,11 @@ are per batch / per chunk / per cycle / per window, NEVER per pod outside
 a sampled-set membership check, and the analyzers iterate the ≤K-sampled
 span set only. A `tracebuf.ACTIVE.instant(...)` inside a pod-scale loop
 would turn the <1% armed budget into a per-pod ring append at 100k scale.
+
+Profiler spans (obs/recorder.py): StageClock boundaries (enter/drop/finish),
+solve parts (`with part(...)`) and the outside stages' spans (`with
+span(...)`, jax.profiler.TraceAnnotation) are per batch, per bind chunk or
+per solver group. One inside a pod-scale loop is the same multiplier.
 """
 
 from __future__ import annotations
@@ -73,10 +78,18 @@ INSTRUMENTATION_CALLS = {"observe", "observe_many", "inc", "set", "mark",
                          "note_self_time", "event", "log", "info", "warning",
                          "debug", "error", "exception",
                          # trace-buffer taps (obs/tracebuf.py, ISSUE 18)
-                         "instant", "counter", "note_span", "note_batch"}
+                         "instant", "counter", "note_span", "note_batch",
+                         # StageClock boundaries and profiler spans
+                         # (obs/recorder.py)
+                         "enter", "drop", "finish", "span", "part",
+                         "TraceAnnotation", "TraceMe"}
+# the same taps called by bare name (`with _span("sched.bind"):`,
+# `with part("solve.readback"):`, `TraceAnnotation(...)`)
+_SPAN_NAMES = re.compile(r"^_?(span|part|TraceAnnotation|TraceMe)$")
 _METRICY = re.compile(r"^(m|metrics|fr|flightrec|clock|trace|recorder|"
                       r"logger|logging|log|sp|span|spans|tracer|podtrace|"
-                      r"pt|latency|tracebuf|_tracebuf|tb|buf|ACTIVE)$")
+                      r"pt|latency|tracebuf|_tracebuf|tb|buf|ACTIVE|"
+                      r"profiler|_recorder)$")
 
 # the membership guard that legalizes per-pod stamping: any name segment of
 # the `in` comparator matching this (self._sampled, sampled, sampled_set)
@@ -175,6 +188,8 @@ def _instrumentation_desc(call: ast.Call) -> Optional[str]:
             return "perf_counter()"
         if f.id == "Trace":
             return "Trace() construction"
+        if _SPAN_NAMES.match(f.id):
+            return f"profiler span {f.id}()"
         if f.id == "print":
             return "print()"
     return None

@@ -54,6 +54,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.recorder import part
 from ..ops.solver import (
     SolverInputs,
     greedy_scan_solve,
@@ -482,12 +483,16 @@ def repair_solve(inp: SolverInputs, batch, d_max: int, *,
     groups = make_groups(batch)
     n = inp.alloc.shape[0]  # per-CLUSTER static (the waterfill_solve idiom)
     max_group = max(len(m) for m, _ in groups)
-    j_max = bucket_j_max(inp.max_pods, inp.pod_count, n, REPAIR_MAX_SLOTS,
-                         cap_hint=max_group)
+    # host waits on device results are the solve stage's readback part
+    # (obs/recorder.py; a no-op outside a batch's solve stage)
+    with part("solve.readback"):
+        j_max = bucket_j_max(inp.max_pods, inp.pod_count, n,
+                             REPAIR_MAX_SLOTS, cap_hint=max_group)
     if j_max is None:
         return None
 
-    ctx = _RepairContext(inp, batch, d_max, has_gang)
+    with part("solve.readback"):
+        ctx = _RepairContext(inp, batch, d_max, has_gang)
     stats = RepairStats(groups=len(groups),
                         violations={k: 0 for k in _KINDS})
     assignment = np.full(p, -1, dtype=np.int32)
@@ -553,14 +558,15 @@ def repair_solve(inp: SolverInputs, batch, d_max: int, *,
         )
         stats.propose_calls += 1
         chosen = np.full(len(members), -1, dtype=np.int32)
-        got = np.asarray(chosen_nodes)[:len(members)]
+        with part("solve.readback"):
+            got = np.asarray(chosen_nodes)[:len(members)]
+            placed_np = np.asarray(k_per_node).astype(np.int64)
         chosen[:len(got)] = got
         assignment[np.asarray(members)] = chosen
         unplaced = np.asarray(members)[chosen < 0]
         residual.extend(int(i) for i in unplaced)
         placed_j = jnp.asarray(k_per_node)
         ctx.commit_resources(placed_j, pi0)
-        placed_np = np.asarray(k_per_node).astype(np.int64)
         # members may span merged classes with identical cm/chg rows; any
         # one of them attributes the count bump correctly
         ctx.bump(cls, placed_np)
@@ -675,7 +681,8 @@ def repair_solve(inp: SolverInputs, batch, d_max: int, *,
             res_inp, d_max, has_ipa=has_affinity, has_ct=has_ct,
             has_st=bool(batch.st_class.size),
             has_gang=ctx.gang_bonus_np is not None)
-        ra = np.asarray(res_assign)
+        with part("solve.readback"):
+            ra = np.asarray(res_assign)
         assignment[res] = ra
         if (ra < 0).any():
             # parity with the oracle: repair never invents unschedulability.
@@ -687,7 +694,8 @@ def repair_solve(inp: SolverInputs, batch, d_max: int, *,
                 inp, d_max, has_ipa=has_affinity, has_ct=has_ct,
                 has_st=bool(batch.st_class.size),
                 has_gang=ctx.gang_bonus_np is not None)
-            return np.asarray(full).astype(np.int32), stats
+            with part("solve.readback"):
+                return np.asarray(full).astype(np.int32), stats
     return assignment, stats
 
 
@@ -711,10 +719,11 @@ def _check(ctx: _RepairContext, inp: SolverInputs,
         inp.ct_class, inp.ct_key, inp.ct_sel, inp.ct_max_skew,
         inp.ct_min_domains,
         d_max=d_max, has_affinity=has_affinity, has_ct=has_ct)
-    v_rn = np.asarray(v_rn)[:p]
-    v_ea = np.asarray(v_ea)[:p]
-    v_ra = np.asarray(v_ra)[:p]
-    v_ct = np.asarray(v_ct)[:p]
+    with part("solve.readback"):
+        v_rn = np.asarray(v_rn)[:p]
+        v_ea = np.asarray(v_ea)[:p]
+        v_ra = np.asarray(v_ra)[:p]
+        v_ct = np.asarray(v_ct)[:p]
     stats.violations[KIND_ANTI] += int(v_rn.sum())
     stats.violations[KIND_EXISTING_ANTI] += int(v_ea.sum())
     stats.violations[KIND_AFFINITY] += int(v_ra.sum())

@@ -36,6 +36,7 @@ from ..ops.solver import (
     default_normalize,
     INT_MIN,
 )
+from ..obs.recorder import part
 from ..scheduler.framework import MAX_NODE_SCORE
 
 
@@ -168,7 +169,10 @@ def waterfill_solve(inp: SolverInputs, groups: List[Tuple[np.ndarray, int]]):
     # slots at ~2.6M; gang batches add GANG_SLICE_BONUS to the score range,
     # so their slot cap tightens to ~2.3M
     max_slots = 2_300_000 if has_gang else 2_600_000
-    j_max = bucket_j_max(inp.max_pods, inp.pod_count, n, max_slots)
+    # host waits on device results are the solve stage's readback part
+    # (obs/recorder.py; a no-op outside a batch's solve stage)
+    with part("solve.readback"):
+        j_max = bucket_j_max(inp.max_pods, inp.pod_count, n, max_slots)
     if j_max is None:
         return None
     assignment = np.full(p, -1, dtype=np.int32)
@@ -179,7 +183,8 @@ def waterfill_solve(inp: SolverInputs, groups: List[Tuple[np.ndarray, int]]):
 
     for members, cls in groups:
         pi0 = int(members[0])
-        has_port = bool(np.asarray(inp.class_ports[cls]).any())
+        with part("solve.readback"):
+            has_port = bool(np.asarray(inp.class_ports[cls]).any())
         port_conflict = jnp.any(port_taken & inp.class_ports[cls][None, :], axis=1)
         # pow2 bucket keeps the jit key stable across batch sizes; never wider
         # than the slot count (top_k requires k <= size). Floored at 256 so
@@ -199,7 +204,8 @@ def waterfill_solve(inp: SolverInputs, groups: List[Tuple[np.ndarray, int]]):
             has_gang=has_gang,
         )
         chosen = np.full(len(members), -1, dtype=np.int32)
-        got = np.asarray(chosen_nodes)[: len(members)]
+        with part("solve.readback"):
+            got = np.asarray(chosen_nodes)[: len(members)]
         chosen[: len(got)] = got  # k_slots may be < group size: overflow stays -1
         assignment[np.asarray(members)] = chosen
         # commit group effects

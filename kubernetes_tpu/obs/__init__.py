@@ -9,7 +9,11 @@ reconcile controllers, the store's watch bus) can inherit the same
 machinery instead of reinventing weaker copies:
 
   obs.recorder   — StageClock + RingRecorder (the generic bounded ring with
-                   per-stage totals/histograms and the p50/p99 stage table).
+                   per-stage totals/histograms and the p50/p99 stage table);
+                   StageClock's stages are jax.profiler TraceMe spans.
+  obs.gcpause    — the process-wide garbage-collection pause counter (one
+                   gc.callbacks hook) the flight recorder and the resource
+                   sampler read.
   obs.reconcile  — ReconcileRecorder: per-loop reconcile spans for
                    controllers/base.py, plus the live-controller registry
                    behind GET /debug/controlstats and `ktl controller stats`.

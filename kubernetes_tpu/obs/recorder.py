@@ -22,6 +22,8 @@ import time
 from collections import deque
 from typing import Dict, List, Optional
 
+from . import gcpause
+
 # Windowed per-stage latency buckets (ISSUE 7): log-spaced 0.2ms..~42s so
 # the p50/p99 estimates survive ring eviction at bounded memory. The ~1.55x
 # bucket ratio bounds the interpolation error well inside the headroom any
@@ -36,26 +38,210 @@ def nearest_rank(sorted_vals: List[float], q: float) -> float:
                            max(0, math.ceil(q * len(sorted_vals)) - 1))]
 
 
+# -- spans on the profiler's clock ----------------------------------------------
+#
+# Every stage boundary is also a TraceMe span (jax.profiler.TraceAnnotation),
+# so a jax.profiler trace shows the batch pipeline on the host line of the
+# thread that ran it, on the same clock as the device's ops. With no trace
+# active a span costs about half a microsecond; spans are per batch, per
+# bind chunk or per solver group, never per pod.
+
+SPAN_PREFIX = "sched."
+BATCH_SPAN = "sched.batch"
+# stages split into parts: the stage's time outside every named part is its
+# `<stage>.host` part, so the parts sum to the stage
+PARTED_STAGES = ("solve",)
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+_TraceMe = None
+_tls = threading.local()
+_listener_lock = threading.Lock()
+_listener_installed = False
+
+
+def _traceme():
+    global _TraceMe
+    if _TraceMe is None:
+        from jax.profiler import TraceAnnotation
+
+        _TraceMe = TraceAnnotation
+    return _TraceMe
+
+
+def span(name: str):
+    """A TraceMe span for `with span("sched.bind"): ...` — the stages that
+    run outside a batch's StageClock (bind worker chunks, bind waits, queue
+    admission)."""
+    return _traceme()(name)
+
+
+def _open_span(name: str):
+    sp = _traceme()(name)
+    sp.__enter__()
+    return sp
+
+
+class part:
+    """`with part("solve.readback"): ...` attributes the enclosed time to a
+    part of the stage open on this thread's StageClock, and restores the
+    part that was open before on exit. A no-op where no StageClock is open
+    on the thread or the open stage is not split (a solver called outside a
+    batch's solve stage)."""
+
+    __slots__ = ("name", "_clock", "_prev")
+
+    def __init__(self, name: str):
+        self.name = name
+        self._clock = self._prev = None
+
+    def __enter__(self):
+        clock = getattr(_tls, "clock", None)
+        if clock is not None:
+            self._prev = clock._switch_part(self.name)
+            if self._prev is not None:
+                self._clock = clock
+        return self
+
+    def __exit__(self, *exc):
+        if self._clock is not None:
+            self._clock._switch_part(self._prev)
+            self._clock = None
+        return False
+
+
+def _on_compile(event: str, duration: float, **_kw) -> None:
+    """jax.monitoring listener: JAX calls it synchronously on the compiling
+    thread, so the thread's open StageClock is the stage that paid."""
+    if event != COMPILE_EVENT:
+        return
+    clock = getattr(_tls, "clock", None)
+    if clock is not None:
+        clock._note_compile(duration)
+
+
+def install_compile_listener() -> None:
+    """Register the one compile-duration listener of the process (idempotent;
+    the flight recorder's enable switch decides whether it is called)."""
+    global _listener_installed
+    with _listener_lock:
+        if _listener_installed:
+            return
+        from jax import monitoring
+
+        monitoring.register_event_duration_secs_listener(_on_compile)
+        _listener_installed = True
+
+
 class StageClock:
-    """Per-batch stage boundary marks. mark(name) attributes the time since
-    the previous boundary; skip() moves the boundary without attributing
-    (work another accumulator already claimed)."""
+    """Per-batch stage boundaries. enter(name) closes the open stage, whose
+    time since the previous boundary is attributed to it, and opens `name`
+    (None: an unattributed stretch); drop(name) closes the open stage
+    without attributing it (work another accumulator claims, or nothing
+    worth a row). Each stage is a TraceMe span `sched.<stage>` inside one
+    `sched.batch` span, and `bounds` keeps each closed stage's (name,
+    begin, end) on perf_counter.
 
-    __slots__ = ("t0", "_last", "stages")
+    While a stage of PARTED_STAGES is open, part() splits it (`parts`,
+    seconds, summing to the stage). XLA compiles that JAX reports on this
+    thread accrue to the open stage and part (`compile_s`, `compiles`);
+    garbage-collection pauses on any thread while the clock is open are
+    `gc_s` and `gc_collections` (per generation) after finish()."""
 
-    def __init__(self):
+    __slots__ = ("t0", "_last", "stages", "bounds", "_open", "_span",
+                 "_batch_span", "_part", "_part_t", "_part_span", "parts",
+                 "compile_s", "compiles", "gc_s", "gc_collections", "_gc0")
+
+    def __init__(self, first: Optional[str] = None):
         self.t0 = self._last = time.perf_counter()
         self.stages: Dict[str, float] = {}
+        self.bounds: List[tuple] = []
+        self.parts: Dict[str, float] = {}
+        self.compile_s: Dict[str, float] = {}
+        self.compiles = 0
+        self.gc_s = 0.0
+        self.gc_collections = [0, 0, 0]
+        self._gc0 = gcpause.COUNTER.snapshot()
+        self._open: Optional[str] = None
+        self._span = self._part = self._part_span = None
+        self._part_t = 0.0
+        self._batch_span = _open_span(BATCH_SPAN)
+        _tls.clock = self
+        if first is not None:
+            self.enter(first)
 
-    def mark(self, name: str) -> float:
-        now = time.perf_counter()
-        dt = now - self._last
-        self.stages[name] = self.stages.get(name, 0.0) + dt
+    def _close(self, now: float, attribute: bool) -> None:
+        name = self._open
+        if name is None:
+            return
+        if self._part is not None:
+            self._switch_part(None, now)
+        if attribute:
+            self.stages[name] = self.stages.get(name, 0.0) + now - self._last
+            self.bounds.append((name, self._last, now))
+        elif name in PARTED_STAGES:
+            # the parts of a stage left out of the table go with it
+            for k in [k for k in self.parts if k.startswith(name + ".")]:
+                del self.parts[k]
+        self._span.__exit__(None, None, None)
+        self._span = self._open = None
+
+    def _begin(self, name: Optional[str], now: float) -> None:
         self._last = now
-        return dt
+        if name is None:
+            return
+        self._open = name
+        self._span = _open_span(SPAN_PREFIX + name)
+        if name in PARTED_STAGES:
+            self._part = f"{name}.host"
+            self._part_t = now
+            self._part_span = _open_span(self._part)
 
-    def skip(self) -> None:
-        self._last = time.perf_counter()
+    def enter(self, name: Optional[str]) -> None:
+        now = time.perf_counter()
+        self._close(now, attribute=True)
+        self._begin(name, now)
+
+    def drop(self, name: Optional[str] = None) -> None:
+        now = time.perf_counter()
+        self._close(now, attribute=False)
+        self._begin(name, now)
+
+    def _switch_part(self, name: Optional[str],
+                     now: Optional[float] = None) -> Optional[str]:
+        """Close the open part and open `name` (None: close only). Returns
+        the part that was open, None when no split stage is open."""
+        prev = self._part
+        if prev is None:
+            return None
+        if now is None:
+            now = time.perf_counter()
+        self.parts[prev] = self.parts.get(prev, 0.0) + now - self._part_t
+        self._part_span.__exit__(None, None, None)
+        self._part_t = now
+        self._part = name
+        self._part_span = _open_span(name) if name is not None else None
+        return prev
+
+    def _note_compile(self, seconds: float) -> None:
+        self.compiles += 1
+        key = self._open or "batch"
+        self.compile_s[key] = self.compile_s.get(key, 0.0) + seconds
+        if self._part is not None:
+            self.compile_s[self._part] = (self.compile_s.get(self._part, 0.0)
+                                          + seconds)
+
+    def finish(self) -> None:
+        """Close the spans (an open stage is dropped: a stage that an
+        exception cut short was never marked), read the GC counter, and
+        stop receiving this thread's compiles. Idempotent."""
+        if self._batch_span is None:
+            return
+        self._close(time.perf_counter(), attribute=False)
+        self._batch_span.__exit__(None, None, None)
+        self._batch_span = None
+        if getattr(_tls, "clock", None) is self:
+            _tls.clock = None
+        self.gc_s, self.gc_collections = gcpause.COUNTER.since(self._gc0)
 
     def add(self, name: str, seconds: float) -> None:
         if seconds > 0:

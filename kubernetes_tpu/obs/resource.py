@@ -7,9 +7,9 @@ A background thread samples at a fixed interval (default 1s):
   alloc_blocks  sys.getallocatedblocks() — the deterministic live-object
                 signal the leak gates fit a slope over (RSS is noisy: the
                 allocator keeps arenas; leaked OBJECTS always grow this);
-  gc            gen counts (gc.get_count), collections/collected since
-                start, and measured pause seconds via gc.callbacks
-                (start/stop pairs around each collection);
+  gc            gen counts (gc.get_count), collections since start, and
+                measured pause seconds from the process-wide gc.callbacks
+                counter (obs/gcpause.py) that the flight recorder reads too;
   threads       per-REGISTERED-thread CPU seconds — the scheduling, bind,
                 and partition drive threads register themselves so the
                 partition A/B can be JUDGED when the rig regrows cores:
@@ -45,6 +45,7 @@ import weakref
 from collections import deque
 from typing import Dict, List, Optional
 
+from . import gcpause as _gcpause
 from . import tracebuf as _tracebuf
 
 DEFAULT_INTERVAL_S = 1.0
@@ -150,12 +151,11 @@ class ResourceSampler:
         self._thread: Optional[threading.Thread] = None
         self.clock = (probe_thread_clock() if clock_probe
                       else {"source": "unavailable", "resolution_s": None})
-        # gc pause accounting via gc.callbacks (registered on start())
-        self._gc_cb_installed = False
-        self._gc_t0 = 0.0
-        self._gc_pause_s = 0.0
-        self._gc_pause_max_s = 0.0
-        self._gc_collections = 0
+        # gc pauses: read from the process-wide counter (obs/gcpause.py),
+        # against a baseline that reset() moves
+        _gcpause.COUNTER.install()
+        self._gc0 = _gcpause.COUNTER.snapshot()
+        self._gc_max = _gcpause.COUNTER.max_cell()
         self.samples_taken = 0
         self.self_seconds = 0.0
         self._t_start = time.perf_counter()
@@ -194,30 +194,13 @@ class ResourceSampler:
             return None
         return getattr(t, "native_id", None)
 
-    # -- gc pause hooks --------------------------------------------------------
+    # -- gc pauses ---------------------------------------------------------------
 
-    def _gc_callback(self, phase: str, info: Dict) -> None:
-        if phase == "start":
-            self._gc_t0 = time.perf_counter()
-        elif phase == "stop" and self._gc_t0:
-            dt = time.perf_counter() - self._gc_t0
-            self._gc_pause_s += dt
-            if dt > self._gc_pause_max_s:
-                self._gc_pause_max_s = dt
-            self._gc_collections += 1
-
-    def _install_gc_cb(self) -> None:
-        if not self._gc_cb_installed:
-            gc.callbacks.append(self._gc_callback)
-            self._gc_cb_installed = True
-
-    def _remove_gc_cb(self) -> None:
-        if self._gc_cb_installed:
-            try:
-                gc.callbacks.remove(self._gc_callback)
-            except ValueError:
-                pass
-            self._gc_cb_installed = False
+    def _gc_columns(self) -> Dict:
+        pause_s, by_gen = _gcpause.COUNTER.since(self._gc0)
+        return {"collections": sum(by_gen),
+                "pause_s": round(pause_s, 6),
+                "pause_max_s": round(self._gc_max.value, 6)}
 
     # -- sampling --------------------------------------------------------------
 
@@ -259,12 +242,7 @@ class ResourceSampler:
                 "ts": t0,
                 "rss_mb": round(self._read_rss_mb(), 3),
                 "alloc_blocks": sys.getallocatedblocks(),
-                "gc": {
-                    "gen_counts": list(counts),
-                    "collections": self._gc_collections,
-                    "pause_s": round(self._gc_pause_s, 6),
-                    "pause_max_s": round(self._gc_pause_max_s, 6),
-                },
+                "gc": dict(self._gc_columns(), gen_counts=list(counts)),
                 "process_cpu_s": round(time.process_time(), 6),
                 "threads": threads,
             }
@@ -296,7 +274,6 @@ class ResourceSampler:
     def start(self) -> None:
         if self._thread is not None and self._thread.is_alive():
             return
-        self._install_gc_cb()
         self._stop.clear()
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name="resource-sampler")
@@ -308,7 +285,6 @@ class ResourceSampler:
         if t is not None:
             t.join(timeout=2.0)
         self._thread = None
-        self._remove_gc_cb()
 
     def reset(self) -> None:
         """Drop history and re-baseline (the warmup-exclusion idiom): the
@@ -318,9 +294,8 @@ class ResourceSampler:
             self._cpu0.clear()
             self._cpu_last.clear()
             self._cpu_carry.clear()
-            self._gc_pause_s = 0.0
-            self._gc_pause_max_s = 0.0
-            self._gc_collections = 0
+            self._gc0 = _gcpause.COUNTER.snapshot()
+            self._gc_max.value = 0.0
             self.samples_taken = 0
             self.self_seconds = 0.0
             self._t_start = time.perf_counter()
@@ -351,11 +326,7 @@ class ResourceSampler:
                                                     self._cpu_last[name]), 6)
                        for name in self._cpu_last}
             elapsed = time.perf_counter() - self._t_start
-            gc_col = {
-                "collections": self._gc_collections,
-                "pause_s": round(self._gc_pause_s, 6),
-                "pause_max_s": round(self._gc_pause_max_s, 6),
-            }
+            gc_col = self._gc_columns()
         last = ring[-1] if ring else None
         # overlap: CPU beyond wall inside one sampling interval can only
         # come from threads truly running in parallel (GIL released) — the

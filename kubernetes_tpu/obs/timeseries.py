@@ -135,8 +135,12 @@ class TimeSeriesRecorder:
 
     def __init__(self, window_s: float = DEFAULT_WINDOW_S,
                  capacity: int = DEFAULT_CAPACITY, enabled: bool = True,
-                 stat_sink=None):
+                 stat_sink=None,
+                 clock: Callable[[], float] = time.perf_counter):
         self.window_s = float(window_s)
+        # the one clock windows open and close on (callers that stamp their
+        # own `now` stamp on this clock's axis)
+        self.clock = clock
         self.capacity = capacity
         self.enabled = enabled
         self.stat_sink = stat_sink  # FlightRecorder: self-time budget
@@ -169,7 +173,7 @@ class TimeSeriesRecorder:
         if not self.enabled:
             return
         t0 = time.perf_counter()
-        now = t0 if now is None else now
+        now = self.clock() if now is None else now
         with self._lock:
             w = self._advance_locked(now)
             w.batches += 1
@@ -190,7 +194,7 @@ class TimeSeriesRecorder:
         if not self.enabled:
             return
         t0 = time.perf_counter()
-        now = t0 if now is None else now
+        now = self.clock() if now is None else now
         with self._lock:
             w = self._advance_locked(now)
             w.stage_samples.setdefault(name, []).append(seconds)
@@ -281,9 +285,10 @@ class TimeSeriesRecorder:
         if not self.enabled:
             return []
         t0 = time.perf_counter()
+        now = self.clock()
         with self._lock:
             w = self._open
-            if w is not None and t0 >= w.end:
+            if w is not None and now >= w.end:
                 self._close_locked(w)
                 self._open = None
             out = list(self._ring)

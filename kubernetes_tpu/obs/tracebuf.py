@@ -113,18 +113,29 @@ class TraceBuffer:
     def note_batch(self, track: str, *, t_end: float,
                    stages: Dict[str, float], pods: int, scheduled: int,
                    outcome: str, solver: str,
-                   breaker: Optional[str] = None) -> None:
-        """One schedule_batch envelope: a B/E pair spanning the batch's
-        serial stage time, with each stage as a back-to-back X slice inside
-        it (StageClock insertion order = pipeline order). Breaker state is
-        diffed against the track's last-seen state; a transition lands as an
-        instant event. `t_end` is the perf_counter stamp at the tap site;
-        stage values are SECONDS."""
+                   breaker: Optional[str] = None,
+                   bounds: Optional[List[Tuple[str, float, float]]] = None,
+                   t_begin: Optional[float] = None) -> None:
+        """One schedule_batch envelope: a B/E pair from `t_begin` (the
+        batch's StageClock start) to `t_end`, with each stage as an X slice
+        inside it that starts at the stage's boundary in `bounds`
+        (StageClock.bounds: name, begin, end) and lasts the stage's
+        attributed time. Without bounds the slices are laid back to back,
+        ending at t_end. Breaker state is diffed against the track's
+        last-seen state; a transition lands as an instant event. Stamps are
+        perf_counter values; stage values are SECONDS."""
         t0 = time.perf_counter()
-        total = 0.0
-        for sec in stages.values():
-            total += sec
-        begin = t_end - total
+        if bounds is None:
+            total = 0.0
+            for sec in stages.values():
+                total += sec
+            at = t_end - total
+            bounds = []
+            for name, sec in stages.items():
+                bounds.append((name, at, at + sec))
+                at += sec
+        begin = t_begin if t_begin is not None else (
+            bounds[0][1] if bounds else t_end)
         state = breaker or "closed"
         with self._lock:
             tid = self._tid_locked(track)
@@ -133,16 +144,16 @@ class TraceBuffer:
                 "ts": self._ts(begin), "pid": _PID, "tid": tid,
                 "args": {"pods": pods, "scheduled": scheduled,
                          "outcome": outcome, "solver": solver}})
-            at = begin
-            for name, sec in stages.items():
-                dur = sec * 1e6
+            for name, b0, b1 in bounds:
+                # a stage's attributed time leaves out sub-stages another
+                # bucket claims (queue_add inside ingest)
+                dur = min(b1 - b0, stages.get(name, 0.0)) * 1e6
                 if dur <= 0.0:
                     continue
                 self._push_locked({
                     "name": name, "cat": "stage", "ph": "X",
-                    "ts": self._ts(at), "dur": round(dur, 3),
+                    "ts": self._ts(b0), "dur": round(dur, 3),
                     "pid": _PID, "tid": tid})
-                at += sec
             self._push_locked({
                 "name": "batch", "cat": "sched", "ph": "E",
                 "ts": self._ts(t_end), "pid": _PID, "tid": tid})

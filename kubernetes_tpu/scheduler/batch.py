@@ -23,7 +23,11 @@ import numpy as np
 
 from ..chaos import faultinject as _chaos
 from ..chaos.faultinject import FaultKill
+from ..obs import gcpause as _gcpause
 from ..obs import tracebuf as _tracebuf
+from ..obs.recorder import install_compile_listener
+from ..obs.recorder import part as _part
+from ..obs.recorder import span as _span
 from ..obs.timeseries import TimeSeriesRecorder
 from ..snapshot.tensorizer import TensorCache, build_cluster_tensors, build_pod_batch
 from ..store import (MODIFIED, APIStore, NotFoundError, is_bind_conflict,
@@ -74,6 +78,11 @@ class BatchScheduler(Scheduler):
         # both hold (tests/test_flightrec.py, tests/test_bench_quick.py).
         self.flightrec = FlightRecorder(capacity=flight_capacity,
                                         enabled=flight_recorder)
+        if flight_recorder:
+            # per-batch compile and GC-pause attribution (obs/recorder.py,
+            # obs/gcpause.py): one listener and one hook per process
+            install_compile_listener()
+            _gcpause.COUNTER.install()
         self.queue.stat_sink = self.flightrec
         # sampled pod lifecycle tracer (scheduler/podtrace.py, ISSUE 7):
         # reservoir-samples K pods per window at queue admission, stamps
@@ -241,7 +250,7 @@ class BatchScheduler(Scheduler):
         from ..utils.tracing import Trace
 
         fr = self.flightrec
-        clock = StageClock()
+        clock = StageClock("ingest")
         # queue_add accrues into the recorder's outside bucket at its own
         # call site (inside this pump); difference it out so the "ingest"
         # residual stays disjoint from its sub-stage
@@ -252,11 +261,12 @@ class BatchScheduler(Scheduler):
         for _ in range(8):
             if self.pump_events(max_events=self.batch_size) < self.batch_size:
                 break
-        clock.mark("ingest")
+        clock.enter("pop")
         clock.sub("ingest", fr.outside_seconds("queue_add") - sub0)
         qps = self.queue.pop_batch(self.batch_size, timeout=timeout)
-        clock.mark("pop")
+        clock.enter("tensorize" if qps else None)
         if not qps:
+            clock.finish()
             # no batch to pin these marks to: fold idle pump/poll time into
             # the aggregate buckets (confirm-heavy idle cycles still show)
             for name, sec in clock.stages.items():
@@ -293,6 +303,7 @@ class BatchScheduler(Scheduler):
             self.batches_solved += 1
             t_fin = time.perf_counter()
             total = clock.total()
+            clock.finish()
             for name, sec in clock.stages.items():
                 m.batch_stage_duration.observe(sec, name)
             m.batch_solve_duration.observe(total, outcome)
@@ -314,7 +325,9 @@ class BatchScheduler(Scheduler):
                                           "iterations", None),
                 breaker=(self.breaker.state
                          if self.breaker.state != "closed" else None),
-                error=out.get("batch_error"))
+                error=out.get("batch_error"), parts=clock.parts,
+                compile_s=clock.compile_s, compiles=clock.compiles,
+                gc_s=clock.gc_s, gc_collections=clock.gc_collections)
             # windowed time-series (ISSUE 13): ONE tap per batch, inside the
             # t_fin self-time window so its cost bills to the <2% budget
             self.timeseries.note_batch(
@@ -331,7 +344,8 @@ class BatchScheduler(Scheduler):
                 tb.attach_clock(self.clock)
                 tb.note_batch(
                     self._thread_label("sched"), t_end=t_fin,
-                    stages=clock.stages, pods=len(qps),
+                    stages=clock.stages, bounds=clock.bounds,
+                    t_begin=clock.t0, pods=len(qps),
                     scheduled=out.get("dispatched", 0)
                     + out.get("serial_scheduled", 0),
                     outcome=outcome,
@@ -364,13 +378,13 @@ class BatchScheduler(Scheduler):
         snapshot = self.cache.update_snapshot()
         out["nodes"] = len(snapshot)
         if len(snapshot) == 0:
-            clock.mark("tensorize")
+            clock.enter(None)
             for qp in qps:
                 self._handle_failure(qp, Status.unschedulable("no nodes available to schedule pods"))
             return
 
         cluster, changed_nodes = self._tensor_cache.cluster_tensors(snapshot)
-        clock.mark("tensorize")
+        clock.enter("build_pod_batch")
         trace.step("Tensorized cluster", nodes=len(snapshot))
         pods = [qp.pod for qp in qps]
         store_cols = None
@@ -430,7 +444,9 @@ class BatchScheduler(Scheduler):
             device_idx = np.nonzero(~fallback_mask)[0]
             fallback_idx = np.nonzero(fallback_mask)[0]
         out["fallback"] = int(fallback_idx.size)
-        clock.mark("build_pod_batch")
+        # each stage is named where it begins (its TraceMe span opens there)
+        after_device = "fallback" if len(fallback_idx) else None
+        clock.enter("solve" if device_idx.size else after_device)
         trace.step("Built pod batch", device=int(device_idx.size),
                    fallback=int(fallback_idx.size))
 
@@ -457,7 +473,7 @@ class BatchScheduler(Scheduler):
                 raise  # an injected hard death is not a handled fault
             except Exception as e:
                 self._handle_solver_error(e, qps, device_idx, solver, out, m)
-                clock.mark("solve")
+                clock.enter(after_device)
                 trace.step("Solver failed; batch requeued",
                            error=type(e).__name__)
                 assignment = None
@@ -516,7 +532,10 @@ class BatchScheduler(Scheduler):
                         and bool((np.asarray(sub.gang_rank) >= 0).any())):
                     assignment = self._rank_align_assignment(
                         cluster, sub, assignment, gang_info)
-            clock.mark("solve")
+            # what follows is "assume" when any pod is placed (to_bind is
+            # then non-empty), else the rejects' stage
+            clock.enter("assume" if bool((np.asarray(assignment) >= 0).any())
+                        else "reject")
             trace.step("Device solve done", solver=solver)
             self.podtrace.batch_stage("solve")  # shared per-batch stamp
             # Two phases: bind every device assignment FIRST, then handle the
@@ -669,7 +688,7 @@ class BatchScheduler(Scheduler):
                                                bind_rows, bind_nodes,
                                                batch_has_ports)
                         accounted = True
-                    clock.mark("assume")
+                    clock.enter("dispatch")
                     trace.step("Assumed placements", bound=len(to_bind))
                     self.podtrace.batch_stage("assume")
                     out["dispatched"] = len(to_bind)
@@ -696,7 +715,7 @@ class BatchScheduler(Scheduler):
                         e, to_bind, bind_gang, dispatched_hi, use_columnar,
                         accounted, batch_has_ports, m, out)
                     out["dispatched"] = dispatched_hi
-                clock.mark("dispatch")
+                clock.enter("reject")
                 # synchronous binds ran inside the dispatch span AND are
                 # observed as the "bind" stage by _bind_batch — keep the
                 # stages disjoint (measured locally, so this holds with the
@@ -724,10 +743,10 @@ class BatchScheduler(Scheduler):
                                     preempt_ctx=preempt_ctx,
                                     gang_info=gang_info)
             if rejected or gang_requeue:
-                clock.mark("reject")
+                clock.enter(after_device)
                 trace.step("Handled rejects", rejected=len(rejected))
             else:
-                clock.skip()
+                clock.drop(after_device)
 
         # Serial fallback, in original priority order among themselves.
         # Gang members never reach here: a gang touching a serial-fallback
@@ -740,7 +759,7 @@ class BatchScheduler(Scheduler):
             for pi in fallback_idx:
                 self._serial_one(qps[pi])
             out["serial_scheduled"] = self.scheduled_count - fb0
-            clock.mark("fallback")
+            clock.enter(None)
             trace.step("Serial fallback done", pods=len(fallback_idx))
 
     def _solve_device(self, solver, cluster, batch, sub, has_gang,
@@ -777,30 +796,37 @@ class BatchScheduler(Scheduler):
         if _chaos.ACTIVE is not None:
             _chaos.ACTIVE.fire("solver.solve")
         assignment = None
+        # the solve stage's parts (obs/recorder.py): the uploads, the
+        # solver's dispatch with its eager ops, and the host's waits on
+        # device results; the rest of the stage is solve.host
         if solver == "native" and constraint_free and not has_gang:
             from ..native import native_available, native_greedy_solve
 
             if native_available():
                 self._solve_path = "native"
-                assignment, _ = native_greedy_solve(cluster, sub)
+                with _part("solve.kernel"):
+                    assignment, _ = native_greedy_solve(cluster, sub)
                 if assignment is None:
                     self._solve_path = "exact"
         # device upload happens only for paths that consume it; cluster
         # tensors ride the persistent HBM mirrors (diff streaming)
         inputs = d_max = None
         if assignment is None:
-            inputs, d_max = make_inputs(
-                cluster, sub,
-                device=self._tensor_cache.device_views(cluster))
+            with _part("solve.upload"):
+                inputs, d_max = make_inputs(
+                    cluster, sub,
+                    device=self._tensor_cache.device_views(cluster))
         if use_transport:
             from ..models.transport import transport_solve
             from ..models.waterfill import make_groups
 
             self._solve_path = solver
-            solved = transport_solve(
-                inputs, make_groups(sub), method=solver,
-                state=self.transport_state, node_names=cluster.node_names,
-            )
+            groups = make_groups(sub)
+            with _part("solve.kernel"):
+                solved = transport_solve(
+                    inputs, groups, method=solver,
+                    state=self.transport_state,
+                    node_names=cluster.node_names)
             if solved is not None:
                 assignment, self.transport_state = solved
             else:
@@ -809,13 +835,16 @@ class BatchScheduler(Scheduler):
             from ..models.waterfill import make_groups, waterfill_solve
 
             self._solve_path = "fast"
-            assignment = waterfill_solve(inputs, make_groups(sub))
+            groups = make_groups(sub)
+            with _part("solve.kernel"):
+                assignment = waterfill_solve(inputs, groups)
         if use_repair:
             from ..models.repair import repair_solve
 
-            solved = repair_solve(
-                inputs, sub, d_max,
-                has_gang=bool(has_gang and sub.gang_bonus is not None))
+            with _part("solve.kernel"):
+                solved = repair_solve(
+                    inputs, sub, d_max,
+                    has_gang=bool(has_gang and sub.gang_bonus is not None))
             if solved is not None:
                 assignment, rstats = solved
                 self._note_repair(rstats)
@@ -827,12 +856,14 @@ class BatchScheduler(Scheduler):
             # static gates: constraint-free batches compile the scan
             # variant without IPA gathers / PTS segment sums
             self._solve_path = "exact"
-            assignment, _, _ = greedy_scan_solve(
-                inputs, d_max, has_ipa=bool(batch.ipa.has_any),
-                has_ct=bool(batch.ct_class.size),
-                has_st=bool(batch.st_class.size),
-                has_gang=bool(has_gang and sub.gang_bonus is not None))
-        return np.asarray(assignment)
+            with _part("solve.kernel"):
+                assignment, _, _ = greedy_scan_solve(
+                    inputs, d_max, has_ipa=bool(batch.ipa.has_any),
+                    has_ct=bool(batch.ct_class.size),
+                    has_st=bool(batch.st_class.size),
+                    has_gang=bool(has_gang and sub.gang_bonus is not None))
+        with _part("solve.readback"):
+            return np.asarray(assignment)
 
     def _note_repair(self, rstats) -> None:
         """Fold one constrained batch's RepairStats into the metrics and the
@@ -1832,7 +1863,8 @@ class BatchScheduler(Scheduler):
     def _bind_batch(self, items) -> None:
         t0 = time.perf_counter()
         try:
-            self._bind_batch_inner(items)
+            with _span("sched.bind"):
+                self._bind_batch_inner(items)
         finally:
             t1 = time.perf_counter()
             self.flightrec.add_outside("bind", t1 - t0)
@@ -2027,12 +2059,13 @@ class BatchScheduler(Scheduler):
         t0 = time.perf_counter()
         if self._bind_worker is not None:
             q = self._bind_q
-            while True:
-                with q.all_tasks_done:
-                    if not q.unfinished_tasks:
-                        break
-                    q.all_tasks_done.wait(timeout=0.05)
-                self._check_bind_worker_alive()
+            with _span("sched.bind_wait"):
+                while True:
+                    with q.all_tasks_done:
+                        if not q.unfinished_tasks:
+                            break
+                        q.all_tasks_done.wait(timeout=0.05)
+                    self._check_bind_worker_alive()
         self.flightrec.add_outside("bind_wait", time.perf_counter() - t0)
         self._drain_bind_results()
 

@@ -6,10 +6,11 @@ answers this with per-extension-point histograms and utiltrace steps
 (schedule_one.go:411); placement-quality work (Tesserae, CvxCluster —
 PAPERS.md) additionally needs per-decision attribution. Three pieces:
 
-  StageClock     — cheap per-BATCH wall-clock marks (one perf_counter read per
-                   stage boundary, never per pod; a 100k-pod batch pays ~10
-                   reads total, so the <2% overhead budget holds by
-                   construction).
+  StageClock     — cheap per-BATCH stage boundaries (one perf_counter read
+                   and one TraceMe span per stage boundary, never per pod;
+                   a 100k-pod batch pays ~10 of each), with the solve
+                   stage's parts and the batch's compile and GC counters
+                   (obs/recorder.py, obs/gcpause.py).
   FlightRecorder — bounded ring of per-batch records: pod/node counts,
                    per-stage ms, outcome, gang veto/release counts,
                    preemption victims, unschedulable-reason attribution, and
@@ -66,6 +67,10 @@ OUTSIDE_STAGES = ("queue_add", "bind", "bind_wait")
 OVERLAPPED_STAGES = ("bind",)
 
 
+def _ms(seconds: Optional[Dict[str, float]]) -> Dict[str, float]:
+    return {k: round(v * 1000, 3) for k, v in (seconds or {}).items()}
+
+
 class FlightRecorder(RingRecorder):
     """Bounded ring of per-batch trace records (last N batches)."""
 
@@ -94,9 +99,20 @@ class FlightRecorder(RingRecorder):
                repair: Optional[Dict] = None,
                solver_iterations: Optional[int] = None,
                breaker: Optional[str] = None,
-               error: Optional[str] = None) -> Optional[Dict]:
-        """Append one batch record (stage values in SECONDS; stored as ms).
-        Returns the record, or None when disabled."""
+               error: Optional[str] = None,
+               parts: Optional[Dict[str, float]] = None,
+               compile_s: Optional[Dict[str, float]] = None,
+               compiles: int = 0, gc_s: float = 0.0,
+               gc_collections: Optional[List[int]] = None
+               ) -> Optional[Dict]:
+        """Append one batch record (stage, part, compile and GC values in
+        SECONDS; stored as ms). parts split a stage (StageClock.parts:
+        `solve.upload`, `solve.kernel`, `solve.readback`, `solve.host`,
+        summing to `solve`); compile_s maps the stage, and the part, that
+        each XLA compile ran in (`batch` when between stages; a stage's
+        entry includes its parts'); gc_s is the garbage-collection pause
+        time, any thread, while the batch was open. Returns the record, or
+        None when disabled."""
         if not self.enabled:
             return None
         with self._lock:
@@ -121,6 +137,11 @@ class FlightRecorder(RingRecorder):
                 "breaker": breaker,
                 "error": error,
                 "bind_failures": list(self._pending_bind_failures),
+                "parts_ms": _ms(parts),
+                "compile_ms": _ms(compile_s),
+                "compiles": compiles,
+                "gc_ms": round(gc_s * 1000, 3),
+                "gc_collections": list(gc_collections or (0, 0, 0)),
             }
             self._pending_bind_failures.clear()
             return self._append_record(rec, stages)

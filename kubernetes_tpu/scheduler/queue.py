@@ -27,6 +27,7 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
 from ..api import Pod
+from ..obs.recorder import span as _span
 from ..utils import Clock
 
 DEFAULT_POD_INITIAL_BACKOFF = 1.0  # seconds (scheduler.go:252)
@@ -174,7 +175,8 @@ class SchedulingQueue:
             admitted = []
             t0 = _time.perf_counter()
             try:
-                admitted = self._add_batch_locked(pods, pre_gated)
+                with _span("sched.queue_add"):
+                    admitted = self._add_batch_locked(pods, pre_gated)
             finally:
                 t1 = _time.perf_counter()
                 sink.add_outside("queue_add", t1 - t0)
@@ -183,7 +185,8 @@ class SchedulingQueue:
                 m.batch_stage_duration.observe(t1 - t0, "queue_add")
                 sink.note_self_time(_time.perf_counter() - t1)
         else:
-            admitted = self._add_batch_locked(pods, pre_gated)
+            with _span("sched.queue_add"):
+                admitted = self._add_batch_locked(pods, pre_gated)
         ts = self.trace_sink
         if ts is not None and admitted:
             # reservoir sampling at admission (scheduler/podtrace.py), with

@@ -97,19 +97,26 @@ class TestRingBuffer:
 
 class TestStageClock:
     def test_marks_are_disjoint_and_sum_to_total(self):
-        clock = StageClock()
-        clock.mark("a")
-        clock.mark("b")
-        clock.skip()  # unattributed span
-        clock.mark("c")
+        clock = StageClock("a")
+        clock.enter("b")
+        clock.enter("x")
+        clock.drop("c")  # x is left out of the table
+        clock.enter(None)
         total = clock.total()
+        clock.finish()
         assert set(clock.stages) == {"a", "b", "c"}
         assert sum(clock.stages.values()) <= total
+        # each closed stage keeps its boundaries, in order and disjoint
+        assert [b[0] for b in clock.bounds] == ["a", "b", "c"]
+        for (_n, t0, t1), (_m, u0, _u1) in zip(clock.bounds,
+                                                clock.bounds[1:]):
+            assert t0 <= t1 <= u0
 
     def test_sub_floors_at_zero(self):
-        clock = StageClock()
-        clock.mark("a")
+        clock = StageClock("a")
+        clock.enter(None)
         clock.sub("a", 10.0)
+        clock.finish()
         assert clock.stages["a"] == 0.0
 
 
@@ -118,7 +125,8 @@ class TestStageClock:
 RECORD_KEYS = {"seq", "ts", "pods", "nodes", "outcome", "solver", "total_ms",
                "stages", "scheduled", "unschedulable", "fallback",
                "preempted", "reasons", "gang", "repair", "solver_iterations",
-               "breaker", "error", "bind_failures"}
+               "breaker", "error", "bind_failures", "parts_ms", "compile_ms",
+               "compiles", "gc_ms", "gc_collections"}
 
 
 class TestRecordSchema:

@@ -1220,6 +1220,43 @@ def test_hp001_quiet_on_per_batch_trace_tap_and_sampled_guard():
 
 
 # ---------------------------------------------------------------------------
+# profiler spans (obs/recorder.py): StageClock boundaries, solve parts and
+# the outside stages' TraceMe spans are per batch / chunk / group taps
+# ---------------------------------------------------------------------------
+
+HP001_SPAN_BAD = '''
+def feed(self, qps, clock, _span):
+    for qp in qps:
+        with _span("sched.pod"):
+            clock.enter("pod")
+'''
+
+HP001_SPAN_GOOD = '''
+def feed(self, qps, clock, _span, part):
+    clock.enter("solve")
+    with part("solve.readback"):
+        rows = [qp.key for qp in qps]
+    with _span("sched.bind"):
+        for qp in qps:
+            if qp.key in self._sampled:
+                clock.enter("sampled")
+    clock.drop(None)
+'''
+
+
+def test_hp001_fires_on_per_pod_span_tap():
+    findings = [f for f in analyze_source(
+        HP001_SPAN_BAD, filename="kubernetes_tpu/scheduler/batch.py")
+        if f.rule == "HP001"]
+    assert len(findings) == 2, findings
+
+
+def test_hp001_quiet_on_per_batch_span_tap():
+    assert "HP001" not in rules_of(analyze_source(
+        HP001_SPAN_GOOD, filename="kubernetes_tpu/scheduler/batch.py"))
+
+
+# ---------------------------------------------------------------------------
 # suppressions
 # ---------------------------------------------------------------------------
 

@@ -93,10 +93,15 @@ class TestTimeSeriesRecorder:
     def test_ring_bounded_under_3x_capacity_churn(self):
         # 3x capacity worth of windows: the ring keeps the newest CAPACITY,
         # seq stays monotonic, nothing leaks
-        ts = TimeSeriesRecorder(window_s=1.0, capacity=8)
+        # the read closes the last window on the recorder's clock, which the
+        # stamps below live on (not the host's perf_counter)
+        now = [2000.0]
+        ts = TimeSeriesRecorder(window_s=1.0, capacity=8,
+                                clock=lambda: now[0])
         for i in range(24):
             ts.note_batch({"solve": 0.001}, pods=1, now=1000.0 + i)
         ts.note_batch({}, now=2000.0)
+        now[0] = 2001.0
         ws = ts.windows()
         assert len(ws) == 8
         assert ts.windows_closed == 25  # 24 churn + the 2000.0 stale close
@@ -104,10 +109,12 @@ class TestTimeSeriesRecorder:
         assert seqs == sorted(seqs) and seqs[-1] >= 24
 
     def test_idle_gap_emits_no_fabricated_windows(self):
-        ts = TimeSeriesRecorder(window_s=1.0)
+        now = [500.0]
+        ts = TimeSeriesRecorder(window_s=1.0, clock=lambda: now[0])
         ts.note_batch({"solve": 0.001}, now=10.0)
         ts.note_batch({"solve": 0.001}, now=500.0)  # long idle gap
-        ws = ts.windows()  # the read closes the open window (real clock)
+        now[0] = 501.5
+        ws = ts.windows()  # the read closes the open window (its clock)
         assert len(ws) == 2  # one per ACTIVE period, no empty filler
         assert ws[1]["start_ts"] == 500.0  # fresh epoch AT the batch
         assert all(w["batches"] == 1 for w in ws)
@@ -401,17 +408,13 @@ class TestResourceSampler:
 
     def test_gc_pause_accounting(self):
         s = ResourceSampler(interval_s=0.1)
-        s._install_gc_cb()
-        try:
-            junk = [[i] for i in range(1000)]
-            del junk
-            gc.collect()
-            rec = s.sample_once()
-            assert rec["gc"]["collections"] >= 1
-            assert rec["gc"]["pause_s"] > 0
-            assert rec["gc"]["pause_max_s"] <= rec["gc"]["pause_s"]
-        finally:
-            s._remove_gc_cb()
+        junk = [[i] for i in range(1000)]
+        del junk
+        gc.collect()
+        rec = s.sample_once()
+        assert rec["gc"]["collections"] >= 1
+        assert rec["gc"]["pause_s"] > 0
+        assert rec["gc"]["pause_max_s"] <= rec["gc"]["pause_s"]
 
     def test_ring_bounded_and_reset(self):
         s = ResourceSampler(interval_s=0.1, capacity=4)
