@@ -288,6 +288,7 @@ class BatchScheduler(Scheduler):
         # single probe of the configured one when HALF_OPEN
         out["solver"] = self.breaker.effective_solver(self.solver)
         self._last_repair = None  # set by _note_repair on the repair path
+        self._tensor_cache.upload = None  # set by a device upload
         m.solver_breaker_state.set(self.breaker.code)
         try:
             self._schedule_batch_inner(qps, clock, trace, m,
@@ -327,7 +328,8 @@ class BatchScheduler(Scheduler):
                          if self.breaker.state != "closed" else None),
                 error=out.get("batch_error"), parts=clock.parts,
                 compile_s=clock.compile_s, compiles=clock.compiles,
-                gc_s=clock.gc_s, gc_collections=clock.gc_collections)
+                gc_s=clock.gc_s, gc_collections=clock.gc_collections,
+                upload=self._tensor_cache.upload)
             # windowed time-series (ISSUE 13): ONE tap per batch, inside the
             # t_fin self-time window so its cost bills to the <2% budget
             self.timeseries.note_batch(

@@ -103,7 +103,8 @@ class FlightRecorder(RingRecorder):
                parts: Optional[Dict[str, float]] = None,
                compile_s: Optional[Dict[str, float]] = None,
                compiles: int = 0, gc_s: float = 0.0,
-               gc_collections: Optional[List[int]] = None
+               gc_collections: Optional[List[int]] = None,
+               upload: Optional[Dict] = None
                ) -> Optional[Dict]:
         """Append one batch record (stage, part, compile and GC values in
         SECONDS; stored as ms). parts split a stage (StageClock.parts:
@@ -111,8 +112,10 @@ class FlightRecorder(RingRecorder):
         summing to `solve`); compile_s maps the stage, and the part, that
         each XLA compile ran in (`batch` when between stages; a stage's
         entry includes its parts'); gc_s is the garbage-collection pause
-        time, any thread, while the batch was open. Returns the record, or
-        None when disabled."""
+        time, any thread, while the batch was open; upload is the HBM
+        mirror update of the batch's device solve (TensorCache.upload: mode,
+        dirty rows, bucket), None without one. Returns the record, or None
+        when disabled."""
         if not self.enabled:
             return None
         with self._lock:
@@ -142,6 +145,7 @@ class FlightRecorder(RingRecorder):
                 "compiles": compiles,
                 "gc_ms": round(gc_s * 1000, 3),
                 "gc_collections": list(gc_collections or (0, 0, 0)),
+                "upload": upload,
             }
             self._pending_bind_failures.clear()
             return self._append_record(rec, stages)

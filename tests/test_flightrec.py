@@ -126,7 +126,7 @@ RECORD_KEYS = {"seq", "ts", "pods", "nodes", "outcome", "solver", "total_ms",
                "stages", "scheduled", "unschedulable", "fallback",
                "preempted", "reasons", "gang", "repair", "solver_iterations",
                "breaker", "error", "bind_failures", "parts_ms", "compile_ms",
-               "compiles", "gc_ms", "gc_collections"}
+               "compiles", "gc_ms", "gc_collections", "upload"}
 
 
 class TestRecordSchema:

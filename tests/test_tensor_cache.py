@@ -8,6 +8,7 @@ full rebuild.
 """
 
 import numpy as np
+import pytest
 
 from kubernetes_tpu.scheduler import Cache, Framework
 from kubernetes_tpu.scheduler.batch import BatchScheduler
@@ -252,3 +253,176 @@ def test_selector_counts_match_a_per_pod_walk():
                 cluster.selcls_count[rn_sel[c, j]], want)
             checked.add(grp)
     assert checked == {f"g{k}" for k in range(5)}
+
+
+# -- dirty-row updates of the HBM mirrors ---------------------------------------
+
+# more nodes than the 2,048-slot bucket: every count below scatters but the last
+BIG = 2100
+LAST = BIG - 1
+
+
+class _Mirrored:
+    """BIG nodes in a scheduler Cache, tensorized by a TensorCache whose HBM
+    mirrors start from a full upload; step(rows) binds one pod to each
+    row's node, then refreshes the host tensors (with a spread batch's
+    selector-class counts when spread) and the mirrors."""
+
+    def __init__(self, spread=False):
+        self.cache = Cache(clock=FakeClock())
+        for i in range(BIG):
+            self.cache.add_node(
+                MakeNode(f"n{i}").labels({ZONE: f"z{i % 4}"})
+                .capacity({"cpu": "64", "memory": "256Gi", "pods": "500"})
+                .obj())
+        self.tc = TensorCache()
+        self.batch_pods = _pods(0, 4, spread=True) if spread else None
+        self.bound = 0
+        self.views = self.step(())
+        assert self.tc.upload["mode"] == "full"
+
+    def step(self, rows):
+        for i in rows:
+            p = MakePod(f"c{self.bound}").labels({"app": "w"}).req(
+                {"cpu": "100m"}).obj()
+            p.spec.node_name = f"n{i}"
+            self.cache.add_pod(p)
+            self.bound += 1
+        snap = self.cache.update_snapshot()
+        self.cluster, changed = self.tc.cluster_tensors(snap)
+        if self.batch_pods is not None:
+            build_pod_batch(self.batch_pods, snap, self.cluster,
+                            reuse=self.tc, changed_nodes=changed)
+        self.views = self.tc.device_views(self.cluster)
+        return self.views
+
+    def assert_mirrors_equal_host(self):
+        for f in TensorCache.DEVICE_FIELDS:
+            np.testing.assert_array_equal(
+                np.asarray(self.views[f]), getattr(self.cluster, f),
+                err_msg=f)
+        if self.batch_pods is not None:
+            np.testing.assert_array_equal(
+                np.asarray(self.views["selcls_count"]),
+                self.cluster.selcls_count)
+
+
+class _Compiles:
+    """Counts XLA backend compiles, by function name, while open."""
+
+    EVENT = "/jax/core/compile/backend_compile_duration"
+
+    def __enter__(self):
+        from jax import monitoring
+
+        self.by_fun = {}
+        monitoring.register_event_duration_secs_listener(self._on)
+        return self
+
+    def _on(self, event, _duration, fun_name="?", **_kw):
+        if event == self.EVENT:
+            self.by_fun[fun_name] = self.by_fun.get(fun_name, 0) + 1
+
+    def __exit__(self, *exc):
+        from jax import monitoring
+
+        monitoring.unregister_event_duration_listener(self._on)
+
+    def total(self):
+        return sum(self.by_fun.values())
+
+
+@pytest.mark.parametrize("selcls", [False, True], ids=["rows", "selcls"])
+@pytest.mark.parametrize("dirty,mode,bucket", [
+    (1, "scatter", 64), (63, "scatter", 64), (64, "scatter", 64),
+    (65, "scatter", 128), (1023, "scatter", 1024), (1024, "scatter", 1024),
+    (1025, "scatter", 2048), (2049, "full", BIG),
+])
+def test_mirror_update_parity_across_bucket_edges(dirty, mode, bucket,
+                                                  selcls):
+    """Each dirty count pads to its bucket (rows padded with the node count,
+    dropped on device) and the mirrors equal the host arrays after every
+    step; the last row, never dirty here, keeps its value (a -1 pad would
+    wrap onto it). A count whose bucket reaches the node count uploads the
+    whole arrays. selcls: a spread batch's selector-class counts take the
+    same update along their node axis."""
+    m = _Mirrored(spread=selcls)
+    before = {f: np.asarray(m.views[f])[LAST].copy()
+              for f in TensorCache.DEVICE_FIELDS}
+    sc_host = m.cluster.selcls_count
+    if selcls:
+        assert sc_host.size
+        sc_before = np.asarray(m.views["selcls_count"]).copy()
+    # two steps: a first and a repeated count, on different rows
+    for offset in (0, LAST - dirty):
+        m.step(range(offset, offset + dirty))
+        assert m.tc.upload == {"mode": mode, "rows": dirty, "bucket": bucket}
+        m.assert_mirrors_equal_host()
+        for f in TensorCache.DEVICE_FIELDS:
+            np.testing.assert_array_equal(
+                np.asarray(m.views[f])[LAST], before[f], err_msg=f)
+    if selcls:
+        # the incremental count path kept the host array, so the mirror took
+        # the column update (or the full upload), and the counts moved
+        assert m.cluster.selcls_count is sc_host
+        sc_now = np.asarray(m.views["selcls_count"])
+        assert (sc_now != sc_before).any()
+        np.testing.assert_array_equal(sc_now[:, LAST], sc_before[:, LAST])
+
+
+def test_mirror_updates_compile_once_per_bucket():
+    """After one update in each bucket, 20 more with distinct dirty counts
+    inside those buckets compile nothing; the eager scatter they replace
+    compiles again for each new count."""
+    m = _Mirrored(spread=True)
+    for dirty in (1, 65, 129, 257, 513):  # buckets 64 .. 1024
+        m.step(range(dirty))
+    counts = [2, 3, 17, 40, 63, 66, 90, 127, 130, 200, 255, 258, 300, 400,
+              511, 514, 600, 777, 900, 1023]
+    assert len(set(counts)) == 20
+    with _Compiles() as seen:
+        for dirty in counts:
+            m.step(range(dirty))
+            assert m.tc.upload["mode"] == "scatter"
+    m.assert_mirrors_equal_host()
+    assert seen.total() == 0, seen.by_fun
+    import jax.numpy as jnp
+
+    host = m.cluster.alloc
+    rows = np.arange(1237)
+    with _Compiles() as eager:
+        jnp.asarray(host).at[rows].set(host[rows]).block_until_ready()
+    assert eager.total() > 0
+
+
+def test_batch_record_carries_the_upload():
+    """Each device batch's flight record says how its mirrors were updated:
+    the first uploads everything, the next scatters the rows the first
+    one's assumes dirtied, and once those placements are confirmed, a batch
+    that places nothing leaves the next one nothing dirty."""
+    store = APIStore()
+    for i in range(80):
+        store.create("nodes", MakeNode(f"n{i}").capacity(
+            {"cpu": "8", "memory": "16Gi", "pods": "50"}).obj())
+    sched = BatchScheduler(store, Framework(default_plugins()),
+                           batch_size=64, solver="fast",
+                           pipeline_binds=False)
+    sched.sync()
+
+    def batch(pods):
+        store.create_many("pods", pods, consume=True)
+        sched.pump_events()
+        assert sched.schedule_batch(timeout=0.0) == len(pods)
+        return sched.flightrec.last()["upload"]
+
+    first = batch([MakePod(f"a{i}").req({"cpu": "100m"}).obj()
+                   for i in range(8)])
+    assert first == {"mode": "full", "rows": 80, "bucket": 80}
+    second = batch([MakePod("huge-0").req({"cpu": "64"}).obj()])
+    assert second["mode"] == "scatter"
+    assert 1 <= second["rows"] <= 8 and second["bucket"] == 64
+    # the bind confirmations dirty the same rows once more, at most
+    later = [batch([MakePod(f"huge-{k}").req({"cpu": "64"}).obj()])
+             for k in range(1, 4)]
+    assert later[-1] == later[-2] == {"mode": "none", "rows": 0, "bucket": 0}
+    assert all(u["mode"] != "full" and u["rows"] <= 8 for u in later)
