@@ -1,9 +1,11 @@
 """Everything a run needs, found by name from BENCHMARK.json: the cell's
-configuration file, its traffic mix (`benchmark/traffic/<traffic>.json`)
-and one reader per metric (`benchmark/metrics/<metric>.py`, whose
-`read(window)` returns the number, or None when it finds nothing to read).
-A cell, a configuration, a mix or a metric is added by adding files and
-entries; nothing here names one.
+configuration file, its traffic mix (`benchmark/traffic/<traffic>.json`),
+one reader per metric (`benchmark/metrics/<metric>.py`, whose
+`read(window)` returns the number, or None when it finds nothing to read)
+and the checks its configuration names (`reference.load_check`). A cell, a
+configuration, a mix, a metric or a check is added by adding files and
+entries; nothing here names one. A configuration whose pod templates state
+a field the pod factory does not know is refused here, when it is loaded.
 """
 
 from __future__ import annotations
@@ -13,6 +15,8 @@ import json
 import os
 from dataclasses import dataclass
 from typing import Callable, Dict, List
+
+from benchmark.deploy import check_template
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -27,6 +31,7 @@ class Cell:
     traffic: dict
     end_to_end: List[dict]
     per_layer: List[dict]
+    root: str = ROOT
 
 
 def load_spec(root: str = ROOT) -> dict:
@@ -48,13 +53,16 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
     configs = {c["name"]: c for c in spec["configs"]}
     with open(os.path.join(root, configs[w["config"]]["file"])) as f:
         config = json.load(f)
+    for t_name, t in config["templates"].items():
+        check_template(t, where=f"{w['config']}: template {t_name}")
     with open(os.path.join(root, "benchmark", "traffic",
                            w["traffic"] + ".json")) as f:
         traffic = json.load(f)
     return Cell(name=name, chips=w["chips"], config_name=w["config"],
                 config=config, traffic_name=w["traffic"], traffic=traffic,
                 end_to_end=[m for m in spec["end_to_end"] if _reports(m, name)],
-                per_layer=[m for m in spec["per_layer"] if _reports(m, name)])
+                per_layer=[m for m in spec["per_layer"] if _reports(m, name)],
+                root=root)
 
 
 def load_reader(metric: str, root: str = ROOT) -> Callable:

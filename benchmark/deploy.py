@@ -1,7 +1,15 @@
 """Bring up the deployment a cell runs against: the served control plane
 (`kadm.init_control_plane`, with every controller it starts), the cluster's
-nodes with a renewed Lease each, and pods built from the configuration's
+nodes with a renewed Lease each and the labels the configuration gives them
+(`reference.node_labels`), and pods built from the configuration's
 templates.
+
+A template states, under the Kubernetes field names, a pod's `requests`,
+`labels`, `topologySpreadConstraints` and `affinity` (node affinity, pod
+affinity and anti-affinity, required and preferred), so upstream's template
+YAML copies into JSON as it stands. A field not in TEMPLATE_FIELDS is an
+error when the configuration is loaded (`catalog.load_cell`), never
+dropped.
 
 Nothing here touches a device at import: the chip belongs to one process.
 """
@@ -11,7 +19,8 @@ from __future__ import annotations
 import threading
 import time
 
-HOST_KEY = "kubernetes.io/hostname"
+from benchmark.reference import node_labels
+
 LEASE_RENEW_S, LEASE_SLICES = 10.0, 20  # kubelet's node-lease renew interval
 PROBE_NAMESPACE = "probe"
 
@@ -24,8 +33,61 @@ def make_nodes(config: dict, names: list) -> list:
     from kubernetes_tpu.testing import MakeNode
 
     cap = config["nodes"]["capacity"]
-    return [MakeNode(n).labels({HOST_KEY: n}).capacity(dict(cap)).obj()
+    labels = node_labels(config, names)
+    return [MakeNode(n).labels(labels[n]).capacity(dict(cap)).obj()
             for n in names]
+
+
+# what a template may state: a key maps to the fields it may hold (None: a
+# value taken whole); a list's one entry is what each of its items may hold
+_SELECTOR = {"matchLabels": None, "matchExpressions": [
+    {"key": None, "operator": None, "values": None}]}
+_NODE_TERM = {"matchExpressions": _SELECTOR["matchExpressions"],
+              "matchFields": _SELECTOR["matchExpressions"]}
+_POD_TERM = {"topologyKey": None, "labelSelector": _SELECTOR,
+             "namespaces": None, "namespaceSelector": _SELECTOR,
+             "matchLabelKeys": None}
+_POD_AFFINITY = {
+    "requiredDuringSchedulingIgnoredDuringExecution": [_POD_TERM],
+    "preferredDuringSchedulingIgnoredDuringExecution": [
+        {"weight": None, "podAffinityTerm": _POD_TERM}]}
+TEMPLATE_FIELDS = {
+    "requests": None,
+    "labels": None,
+    "topologySpreadConstraints": [{
+        "maxSkew": None, "topologyKey": None, "whenUnsatisfiable": None,
+        "labelSelector": _SELECTOR, "minDomains": None,
+        "nodeAffinityPolicy": None, "nodeTaintsPolicy": None,
+        "matchLabelKeys": None}],
+    "affinity": {
+        "nodeAffinity": {
+            "requiredDuringSchedulingIgnoredDuringExecution": {
+                "nodeSelectorTerms": [_NODE_TERM]},
+            "preferredDuringSchedulingIgnoredDuringExecution": [
+                {"weight": None, "preference": _NODE_TERM}]},
+        "podAffinity": _POD_AFFINITY,
+        "podAntiAffinity": _POD_AFFINITY},
+}
+
+
+def check_template(value, fields=TEMPLATE_FIELDS, where: str = "template") -> None:
+    """Raise ValueError naming the first field the pod factory does not
+    know."""
+    if fields is None:
+        return
+    if isinstance(fields, list):
+        if not isinstance(value, list):
+            raise ValueError(f"{where}: a list is expected")
+        for i, v in enumerate(value):
+            check_template(v, fields[0], f"{where}[{i}]")
+        return
+    if not isinstance(value, dict):
+        raise ValueError(f"{where}: an object is expected")
+    for k, v in value.items():
+        if k not in fields:
+            raise ValueError(f"{where}.{k}: a field the pod factory does not "
+                             f"know (it knows {sorted(fields)})")
+        check_template(v, fields[k], f"{where}.{k}")
 
 
 class PodFactory:
@@ -34,11 +96,19 @@ class PodFactory:
     read-only). Names and uids come from the caller, so a seed fixes them."""
 
     def __init__(self, template: dict, namespace: str = "default"):
+        from kubernetes_tpu.api.types import Affinity, TopologySpreadConstraint
         from kubernetes_tpu.testing import MakePod
 
         mp = MakePod("template", namespace).labels(dict(template.get("labels") or {}))
         mp.req(dict(template["requests"]))
-        self._template = mp.obj()
+        pod = mp.obj()
+        if "topologySpreadConstraints" in template:
+            pod.spec.topology_spread_constraints = [
+                TopologySpreadConstraint.from_dict(c)
+                for c in template["topologySpreadConstraints"]]
+        if "affinity" in template:
+            pod.spec.affinity = Affinity.from_dict(template["affinity"])
+        self._template = pod
 
     def make(self, names: list, uid_prefix: str) -> list:
         from kubernetes_tpu.store.store import pod_structural_clone

@@ -4,7 +4,11 @@ was due. It imports nothing but the standard library (above all not JAX:
 the parent holds the chip).
 
     python3 benchmark/probe.py --url URL --rate R --salt S --namespace NS \
-        --requests '{"cpu": "100m", "memory": "500Mi"}'
+        --template '{"requests": {"cpu": "100m", "memory": "500Mi"}}'
+
+The template is a configuration's (`deploy.TEMPLATE_FIELDS`): each pod
+requests, and limits, its `requests`, and carries its `labels`,
+`topologySpreadConstraints` and `affinity` where it states them.
 
 It starts sending at once. A line "stop" on stdin ends the sending; it then
 waits for every request in flight and prints one JSON line: every request
@@ -29,25 +33,32 @@ THREADS = 256  # open loop at 50/s holds up to 5 s of latency in flight
 TIMEOUT_S = 60.0
 
 
-def pod_body(name: str, requests: dict) -> bytes:
-    return json.dumps({
+def pod_body(name: str, template: dict) -> bytes:
+    requests = template["requests"]
+    pod = {
         "apiVersion": "v1", "kind": "Pod",
         "metadata": {"name": name},
         "spec": {"containers": [{
             "name": "pause", "image": "registry.k8s.io/pause:3.10",
             "resources": {"requests": requests, "limits": requests}}]},
-    }).encode()
+    }
+    if template.get("labels"):
+        pod["metadata"]["labels"] = template["labels"]
+    for field in ("topologySpreadConstraints", "affinity"):
+        if field in template:
+            pod["spec"][field] = template[field]
+    return json.dumps(pod).encode()
 
 
 class Probe:
     def __init__(self, url: str, rate: float, salt: str, namespace: str,
-                 requests: dict):
+                 template: dict):
         u = urllib.parse.urlparse(url)
         self.host, self.port = u.hostname, u.port
         self.gap = 1.0 / rate
         self.salt = salt
         self.ns = namespace
-        self.requests = requests
+        self.template = template
         self.results: list = []
         self._lock = threading.Lock()
         self._stop = threading.Event()
@@ -80,7 +91,7 @@ class Probe:
                 if conn is None:
                     conn = http.client.HTTPConnection(self.host, self.port,
                                                       timeout=TIMEOUT_S)
-                conn.request("POST", path, body=pod_body(name, self.requests),
+                conn.request("POST", path, body=pod_body(name, self.template),
                              headers={"Content-Type": "application/json"})
                 resp = conn.getresponse()
                 resp.read()
@@ -113,10 +124,10 @@ def main(argv=None) -> int:
     ap.add_argument("--rate", type=float, required=True)
     ap.add_argument("--salt", required=True)
     ap.add_argument("--namespace", required=True)
-    ap.add_argument("--requests", required=True, help="JSON resource requests")
+    ap.add_argument("--template", required=True, help="JSON pod template")
     args = ap.parse_args(argv)
     probe = Probe(args.url, args.rate, args.salt, args.namespace,
-                  json.loads(args.requests))
+                  json.loads(args.template))
     threads = [threading.Thread(target=probe.worker, daemon=True)
                for _ in range(THREADS)]
     for t in threads:

@@ -135,11 +135,11 @@ class ProbeChild:
     """The HTTP probe (`probe.py`) as a child process. It never imports JAX."""
 
     def __init__(self, url: str, rate: float, salt: str, namespace: str,
-                 requests: dict):
+                 template: dict):
         self.proc = subprocess.Popen(
             [sys.executable, os.path.join(ROOT, "benchmark", "probe.py"),
              "--url", url, "--rate", repr(rate), "--salt", salt,
-             "--namespace", namespace, "--requests", json.dumps(requests)],
+             "--namespace", namespace, "--template", json.dumps(template)],
             stdin=subprocess.PIPE, stdout=subprocess.PIPE, text=True)
         self.t_stop = None
 
@@ -195,7 +195,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, cut: int,
     from benchmark.compiles import CompileCounter
     from benchmark.deploy import PROBE_NAMESPACE, Deployment, PodFactory
     from benchmark.informer import Informer
-    from benchmark.reference import LIMITS, PodShape, check
+    from benchmark.reference import PodShape, check, limits
     from benchmark.traffic import Generator
 
     config, traffic = cell.config, cell.traffic
@@ -229,7 +229,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, cut: int,
 
         probe_tmpl = templates[config["probe_pods"]["template"]]
         probe = ProbeChild(dep.url, max(traffic["probe_per_s"] / cut, 1.0), salt,
-                           PROBE_NAMESPACE, probe_tmpl["requests"])
+                           PROBE_NAMESPACE, probe_tmpl)
         measured = templates[config["measured_pods"]["template"]]
         gen = Generator(traffic, store, informer, PodFactory(measured), seed,
                         cut)
@@ -360,9 +360,11 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, cut: int,
     if probe_out.get("readback_error"):
         diag["readback_error"] = probe_out["readback_error"]
     t_ref = time.monotonic()
-    counts = check(informer.log, config, dep.names, shape_of, acked, readback)
+    counts = check(informer.log, config, dep.names, shape_of, acked, readback,
+                   cell.root)
     diag["reference_s"] = time.monotonic() - t_ref
-    checks = {c: {"value": v, "limit": LIMITS[c]} for c, v in counts.items()}
+    lims = limits(config, cell.root)
+    checks = {c: {"value": v, "limit": lims[c]} for c, v in counts.items()}
 
     # -- the window's readings --------------------------------------------------
     bound_at = informer.bound_at

@@ -1,41 +1,13 @@
 """The harness finds a new cell, configuration, traffic mix and metric from
-added files alone: a throwaway checkout holds one of each, and nothing in
-the harness names them."""
-
-import json
-import os
-import shutil
+added files alone: a throwaway checkout (`checkout.py`) holds them, and
+nothing in the harness names them."""
 
 from benchmark import catalog
 from benchmark.run import Window
-
-HERE = os.path.dirname(os.path.abspath(__file__))
-
-
-def _checkout(tmp_path):
-    root = tmp_path / "checkout"
-    shutil.copytree(os.path.join(HERE, "fixture"), root / "benchmark")
-    spec = {
-        "command": ["python3", "benchmark/run.py"], "paths": ["benchmark"],
-        "run_seconds": 20,
-        "configs": [{"name": "tiny-cfg", "source": "https://example.org/tiny",
-                     "file": "benchmark/configs/tiny-cfg.json", "reduced": [],
-                     "why": "fixture"}],
-        "workloads": [{"name": "tiny-cell", "config": "tiny-cfg",
-                       "traffic": "tiny-mix", "chips": 1, "why": "fixture"}],
-        "end_to_end": [{"name": "setup_s", "unit": "s", "better": "lower",
-                        "bound": 0.25, "source": "host_clock"}],
-        "per_layer": [{"name": "twice_relists", "unit": "relists",
-                       "better": "lower", "source": "program_counter",
-                       "layer": "control plane", "moves": "setup_s",
-                       "workloads": ["tiny-cell"]}],
-    }
-    (root / "BENCHMARK.json").write_text(json.dumps(spec))
-    return str(root)
-
+from benchmark.tests.checkout import make_checkout
 
 def test_new_cell_config_mix_and_metric_come_from_files(tmp_path):
-    root = _checkout(tmp_path)
+    root = make_checkout(tmp_path)
     cell = catalog.load_cell("tiny-cell", root)
     assert cell.config["nodes"]["count"] == 7
     assert cell.traffic["arrivals"] == "poisson"
@@ -50,7 +22,7 @@ def test_new_cell_config_mix_and_metric_come_from_files(tmp_path):
 
 
 def test_metric_that_finds_nothing_is_left_out(tmp_path):
-    root = _checkout(tmp_path)
+    root = make_checkout(tmp_path)
     cell = catalog.load_cell("tiny-cell", root)
     w = Window(window_s=1.0, setup_s=2.0, binds_in_window=0, bind_ms=[],
                api_ms=[], gen_late_ms=[], stages_ms={}, compiles_in_window=0,
@@ -59,7 +31,7 @@ def test_metric_that_finds_nothing_is_left_out(tmp_path):
 
 
 def test_unknown_cell_is_refused(tmp_path):
-    root = _checkout(tmp_path)
+    root = make_checkout(tmp_path)
     try:
         catalog.load_cell("no-such-cell", root)
     except KeyError as e:

@@ -2,7 +2,7 @@
 
 import pytest
 
-from benchmark.reference import PodShape, check, quantity
+from benchmark.reference import PodShape, check, load_check, quantity
 
 PLAIN_T = {"requests": {"cpu": "400m", "memory": "500Mi"}}
 CONFIG = {"nodes": {"capacity": {"cpu": "1", "memory": "2Gi", "pods": "3"}},
@@ -107,3 +107,93 @@ def test_false_unschedulable_only_when_a_node_had_room():
     full += [("B", f"p/{i}", n, 2) for i, n in enumerate("aabbcc")]
     full += [("U", "p/6", None, 3), ("U", "p/6", None, 4)]
     assert run(full)["false_unschedulable"] == 0
+
+
+# spread_skew: six nodes over three zones, given in turn (z1 z2 z3 z1 z2 z3)
+ZONE = "topology.kubernetes.io/zone"
+SPREAD_T = {"requests": {"cpu": "100m", "memory": "1Mi"}, "labels": {"foo": "bar"},
+            "topologySpreadConstraints": [{
+                "maxSkew": 1, "topologyKey": ZONE, "whenUnsatisfiable": "DoNotSchedule",
+                "labelSelector": {"matchLabels": {"foo": "bar"}}}]}
+FILL_T = {"requests": {"cpu": "1", "memory": "1Mi"}}
+SPREAD_CFG = {
+    "nodes": {"capacity": {"cpu": "1", "memory": "2Gi", "pods": "10"},
+              "labelNodePrepareStrategy": {"labelKey": ZONE,
+                                           "labelValues": ["z1", "z2", "z3"]}},
+    "templates": {"spread": SPREAD_T, "fill": FILL_T},
+    "checks": ["overcommit", "false_unschedulable", "spread_skew"]}
+SIX = [f"n{i}" for i in range(6)]
+IN_ZONE = {"z1": ["n0", "n3"], "z2": ["n1", "n4"], "z3": ["n2", "n5"]}
+SHAPES = {"s": PodShape(SPREAD_T), "f": PodShape(FILL_T)}
+
+
+def spread_run(log, config=SPREAD_CFG):
+    def shape(key):
+        return SHAPES[key.split("/", 1)[1][0]]
+
+    return check(log, config, SIX, shape, set(), {})
+
+
+def binds(zones, g, first=0, ns="default"):
+    """Spread pods s<first>..., created and bound one to each zone named,
+    in one delivery."""
+    out = []
+    for i, z in enumerate(zones):
+        key = f"{ns}/s{first + i}"
+        out += [("A", key, None, 0), ("B", key, IN_ZONE[z][i % 2], g)]
+    return [e for e in out if e[0] == "A"] + [e for e in out if e[0] == "B"]
+
+
+def _log(*parts):
+    adds = [e for p in parts for e in p if e[0] == "A"]
+    return adds + [e for p in parts for e in p if e[0] != "A"]
+
+
+def test_spread_skew_reads_0_on_a_sound_placement():
+    # within a delivery the order is free; only the end state is judged
+    log = _log(binds(["z1", "z1", "z2", "z3"], 1), binds(["z2", "z3"], 2, 4),
+               binds(["z1", "z2", "z3"], 3, 6))
+    assert spread_run(log) == {"overcommit": 0, "false_unschedulable": 0,
+                               "spread_skew": 0}
+
+
+def test_a_binding_over_max_skew_counts():
+    log = _log(binds(["z1", "z2", "z3"], 1), binds(["z1", "z1", "z1"], 2, 3))
+    assert spread_run(log)["spread_skew"] == 2  # z1 4, least 1, maxSkew 1
+    # pods its selector does not select, or of another namespace, count not
+    other = [("A", "other/s9", None, 0), ("B", "other/s9", "n0", 3)]
+    fills = [("A", f"default/f{i}", None, 0) for i in range(2)]
+    fills += [("B", "default/f0", "n0", 3), ("B", "default/f1", "n3", 3)]
+    log = _log(binds(["z2", "z3"], 1), other, fills, binds(["z1"], 4, 2))
+    assert spread_run(log)["spread_skew"] == 0
+
+
+def test_a_delete_the_scheduler_had_not_seen_stays_within_the_limit():
+    # z1 z2 z3 hold 2 each; a z3 pod goes, and a batch placed on the view
+    # before that puts one more into z1 (2 + 1 - 2 <= 1 there)
+    log = _log(binds(["z1", "z2", "z3"] * 2, 1), binds(["z1"], 3, 6))
+    log.insert(-1, ("D", "default/s2", None, 2))
+    got = spread_run(log)["spread_skew"]
+    assert 0 < got <= load_check("spread_skew").LIMIT
+
+
+def test_a_pod_only_the_skew_refused_is_no_false_refusal():
+    # z3's nodes are full; z1 and z2 hold one spread pod each and have room,
+    # where a third would break maxSkew 1
+    fills = [("A", f"default/f{i}", None, 0) for i in range(2)]
+    fills += [("B", "default/f0", "n2", 1), ("B", "default/f1", "n5", 1)]
+    log = _log(fills, binds(["z1", "z2"], 2),
+               [("A", "default/s7", None, 0), ("U", "default/s7", None, 3)])
+    assert spread_run(log)["false_unschedulable"] == 0
+    fit_only = dict(SPREAD_CFG, checks=["false_unschedulable"])
+    assert spread_run(log, fit_only)["false_unschedulable"] == 1
+    # with room in z3 the refusal is false again
+    assert spread_run(_log(binds(["z1", "z2"], 2),
+                           [("A", "default/s7", None, 0),
+                            ("U", "default/s7", None, 3)]))["false_unschedulable"] == 1
+
+
+def test_spread_constraints_the_check_does_not_model_are_refused():
+    t = dict(SPREAD_T, affinity={"nodeAffinity": {}})
+    with pytest.raises(ValueError, match="node affinity"):
+        spread_run([], dict(SPREAD_CFG, templates={"spread": t}))

@@ -8,7 +8,8 @@
              pods are.
   "poisson"  open-loop arrivals at `rate_per_s`; each pod is deleted an
              exponential lifetime of mean `lifetime_mean_s` after its
-             binding arrives.
+             binding arrives, or never where `lifetime_mean_s` is null (as
+             scheduler_perf's measured pods live on).
 
 The seed fixes every name, arrival time and lifetime; the sizes are the
 mix's own, so every seed does the same work in another order. Open-loop gaps
@@ -89,8 +90,9 @@ class Generator:
             life = traffic["lifetime_mean_s"]
             self._gaps = Shuffled(self.rng, lambda k, n: np.random.default_rng(
                 [FIXED_STREAM, 0, k]).exponential(1.0 / self.rate, n))
-            self._lives = Shuffled(self.life_rng, lambda k, n: np.random.default_rng(
-                [FIXED_STREAM, 1, k]).exponential(life, n))
+            self._lives = None if life is None else Shuffled(
+                self.life_rng, lambda k, n: np.random.default_rng(
+                    [FIXED_STREAM, 1, k]).exponential(life, n))
             self._deletes: list = []  # heap of (due, key)
         else:
             raise ValueError(f"unknown arrivals {kind!r}")
@@ -161,7 +163,7 @@ class Generator:
                     b = self.burst_of.get(k)
                     if b is not None:
                         self.burst_bound[b] += 1
-        else:
+        elif self._lives is not None:
             with self._lock:
                 mine = [k for k in keys if k in self.due]
                 if mine:
